@@ -1,6 +1,6 @@
 """Batched serving example: continuous batching over decode slots.
 
-    PYTHONPATH=src python examples/serve_lm.py --arch gemma-2b --requests 6
+    PYTHONPATH=src python examples/serve_lm.py --arch granite-moe-1b-a400m --requests 6
 """
 import argparse
 import importlib
@@ -14,7 +14,7 @@ from repro.models import LanguageModel
 
 def main() -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="gemma-2b")
+    ap.add_argument("--arch", default="granite-moe-1b-a400m")
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--max-new", type=int, default=12)
     ap.add_argument("--slots", type=int, default=3)

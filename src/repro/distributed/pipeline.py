@@ -9,24 +9,11 @@ the reverse permute), validated against the sequential reference in tests.
 """
 from __future__ import annotations
 
-import inspect
 from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# jax >= 0.6 renamed check_rep -> check_vma; disable either way (the bodies
-# use collectives that the replication checker cannot verify)
-_SM_NOCHECK = (
-    {"check_vma": False}
-    if "check_vma" in inspect.signature(_shard_map).parameters
-    else {"check_rep": False})
 
 
 def pipeline_apply(
@@ -81,11 +68,13 @@ def pipeline_apply(
         return outs
 
     spec_params = jax.tree.map(lambda _: P(axis), stage_params)
-    out = _shard_map(
+    # check_vma off: the body's ppermute/cond pattern is not something the
+    # varying-manual-axes checker can verify
+    out = jax.shard_map(
         per_stage, mesh=mesh,
         in_specs=(spec_params, P()),
         out_specs=P(axis),  # each stage returns outs; only last is real
-        **_SM_NOCHECK,
+        check_vma=False,
     )(stage_params, x)
     # out has a stage-sharded leading dim view: (n_stages*n_micro, ...) after
     # concat; the real outputs live in the last stage's block

@@ -37,15 +37,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4.x releases
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _flash_kernel(q_ref, k_ref, v_ref, *rest, scale: float, causal: bool,
                   window: int, q_offset: int, kv_len: int, block_q: int,
                   block_k: int, n_kv_blocks: int, out_scale: float,
-                  has_residual: bool):
+                  has_residual: bool, precision):
     if has_residual:
         res_ref, o_ref, acc_ref, m_ref, l_ref = rest
     else:
@@ -65,10 +61,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, *rest, scale: float, causal: bool,
     masked = causal or window > 0 or kv_len > 0
 
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)  # (bq, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (bk, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[0, 0].astype(jnp.float32)  # (bq, D)
+        k = k_ref[0, 0].astype(jnp.float32)  # (bk, D)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                precision=precision,
                                 preferred_element_type=jnp.float32) * scale
 
         if masked:
@@ -94,6 +91,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, *rest, scale: float, causal: bool,
         l_new = l_prev * alpha + jnp.sum(p, axis=-1)
         acc_ref[...] = (acc_ref[...] * alpha[:, None]
                         + jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
+                                              precision=precision,
                                               preferred_element_type=jnp.float32))
         m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
@@ -120,8 +118,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, *rest, scale: float, causal: bool,
         if out_scale != 1.0:
             o = o * out_scale
         if res_ref is not None:
-            o = o + res_ref[0, :, 0, :].astype(jnp.float32)
-        o_ref[0, :, 0, :] = o.astype(o_ref.dtype)
+            o = o + res_ref[0, 0].astype(jnp.float32)
+        o_ref[0, 0] = o.astype(o_ref.dtype)
 
 
 def flash_attention(
@@ -169,35 +167,45 @@ def flash_attention(
         _flash_kernel, scale=scale, causal=causal, window=window,
         q_offset=q_offset, kv_len=kv_len, block_q=block_q, block_k=block_k,
         n_kv_blocks=nk, out_scale=out_scale,
-        has_residual=residual is not None)
+        has_residual=residual is not None,
+        # f32 inputs get f32 matmuls: Mosaic's default rounds f32 operands
+        # to bf16 (1e-2 errors on a v5e); bf16 inputs lose nothing by it
+        precision=(jax.lax.Precision.HIGHEST if q.dtype == jnp.float32
+                   else None))
 
+    # head-major inside the wrapper: every block's last two dims are then
+    # (block, D) -- the (8, 128)-tileable layout Mosaic requires -- instead of
+    # (1 head, D) slices of the (B, S, H, D) interface layout
+    q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+    q_spec = pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq, ik: (b, h, iq, 0))
     in_specs = [
-        pl.BlockSpec((1, block_q, 1, D), lambda b, h, iq, ik: (b, iq, h, 0)),
-        pl.BlockSpec((1, block_k, 1, D), lambda b, h, iq, ik: (b, ik, h // G, 0)),
-        pl.BlockSpec((1, block_k, 1, Dv), lambda b, h, iq, ik: (b, ik, h // G, 0)),
+        q_spec,
+        pl.BlockSpec((1, 1, block_k, D), lambda b, h, iq, ik: (b, h // G, ik, 0)),
+        pl.BlockSpec((1, 1, block_k, Dv), lambda b, h, iq, ik: (b, h // G, ik, 0)),
     ]
+    o_spec = pl.BlockSpec((1, 1, block_q, Dv), lambda b, h, iq, ik: (b, h, iq, 0))
     operands = [q, k, v]
     if residual is not None:
-        in_specs.append(pl.BlockSpec((1, block_q, 1, Dv),
-                                     lambda b, h, iq, ik: (b, iq, h, 0)))
-        operands.append(residual)
+        in_specs.append(o_spec)
+        operands.append(residual.transpose(0, 2, 1, 3))
 
     out = pl.pallas_call(
         kernel,
         grid=(B, Hq, nq, nk),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, block_q, 1, Dv), lambda b, h, iq, ik: (b, iq, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Sq_p, Hq, Dv), q.dtype),
+        out_specs=o_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hq, Sq_p, Dv), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, Dv), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),  # row-max, lane-broadcast
             pltpu.VMEM((block_q, 128), jnp.float32),  # row-sum, lane-broadcast
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(*operands)
+    out = out.transpose(0, 2, 1, 3)
     if pad_q:
         out = out[:, :Sq]
     return out

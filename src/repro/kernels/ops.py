@@ -1,9 +1,9 @@
 """Jit'd dispatch wrappers for the Pallas kernels.
 
-On TPU the compiled kernels run natively; on CPU (this container) the same
-kernel bodies execute in ``interpret=True`` mode for correctness work, and
-model code falls back to the XLA reference path for anything
-performance-shaped (the dry-run lowers the XLA path; see DESIGN.md §6).
+On TPU the compiled kernels run natively.  Elsewhere the same kernel bodies
+run only when the caller passes ``interpret=True`` (the Pallas interpreter,
+for correctness work): left at ``None`` on a backend other than TPU, a call
+raises instead of silently interpreting.
 
 Tile selection (``kernels/autotune.py``) happens *outside* the jit boundary
 so the blocks reach ``pallas_call`` as static values:
@@ -37,6 +37,17 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def _interpret(interpret: bool | None) -> bool:
+    if interpret is not None:
+        return interpret
+    if not _on_tpu():
+        raise ValueError(
+            f"Pallas kernels compile only for TPU, and the backend is "
+            f"{jax.default_backend()!r}: pass interpret=True to run them in "
+            f"the Pallas interpreter")
+    return False
+
+
 def _can_time(*arrays) -> bool:
     """Eager concrete arrays only: a timing search cannot run under trace."""
     return not any(isinstance(a, jax.core.Tracer) for a in arrays)
@@ -56,7 +67,7 @@ def _flash_jit(q, k, v, residual, *, causal, window, scale, q_offset,
 def flash_attention(q, k, v, *, causal=True, window=0, scale=None,
                     q_offset=0, block_q=None, block_k=None, tuned=False,
                     out_scale=1.0, residual=None, interpret=None):
-    interp = (not _on_tpu()) if interpret is None else interpret
+    interp = _interpret(interpret)
     bq, bk = block_q, block_k
     if tuned and (bq is None or bk is None):
         cfg = _resolve_attention(q, k, v, causal=causal, window=window,
@@ -111,7 +122,7 @@ def _scan_jit(r, k, v, log_w, u, s0, *, chunk, interpret):
 
 def linear_scan(r, k, v, log_w, u, s0, *, chunk=None, tuned=False,
                 interpret=None):
-    interp = (not _on_tpu()) if interpret is None else interpret
+    interp = _interpret(interpret)
     c = chunk
     if tuned and c is None:
         c = _resolve_scan(r, k, v, log_w, u, s0, interpret=interp)["chunk"]

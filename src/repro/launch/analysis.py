@@ -221,8 +221,6 @@ def top_collectives(hlo_text: str, k: int = 12) -> list[dict[str, Any]]:
 def safe_cost_analysis(compiled: Any) -> dict[str, float]:
     try:
         ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0]
         return {k: float(v) for k, v in ca.items()
                 if isinstance(v, (int, float, np.floating))}
     except Exception as e:  # pragma: no cover

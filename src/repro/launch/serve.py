@@ -26,15 +26,17 @@ from __future__ import annotations
 import argparse
 import collections
 import dataclasses
-import importlib
 import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.models import LanguageModel
+from repro.configs import get_config
 from repro.launch.paged_kv import PagedKVCache, decompose
+from repro.launch.train import smoke_config
+from repro.models import LanguageModel
+from repro.utils import enable_compile_cache
 
 
 @dataclasses.dataclass
@@ -115,7 +117,8 @@ class PagedServingEngine:
         }
         self._window_walls: list[float] = []  # (wall_s, ticks) per drain gap
 
-        def tick_block(cache, table, last, pos, remaining, out_buf, out_cnt):
+        def tick_block(params, cache, table, last, pos, remaining, out_buf,
+                       out_cnt):
             """``drain_every`` decode ticks in one dispatch: the decode loop
             is device-resident between drains, so per-call overhead (pytree
             flattening, dispatch) is paid once per K tokens per slot."""
@@ -141,7 +144,7 @@ class PagedServingEngine:
                 None, length=drain_every)
             return carry
 
-        def chunk(cache, table, slots, tokens, start, frames):
+        def chunk(params, cache, table, slots, tokens, start, frames):
             """One batched prefill round: G slots advance one chunk each.
             Padded group entries (slot == n_slots, start == -1) gather init
             values, compute garbage, and scatter out of bounds -> dropped."""
@@ -160,12 +163,14 @@ class PagedServingEngine:
             return (last.at[slot].set(tok), pos.at[slot].set(plen),
                     remaining.at[slot].set(max_new))
 
-        # params are closure constants (no per-call flatten of the weight
-        # tree) and the threaded state is donated so XLA updates the multi-MB
-        # cache pools in place instead of copying them every block/chunk
+        # params are arguments, not closure constants: a closed-over array
+        # is embedded in each compiled program, so every program would carry
+        # its own copy of the weights.  The threaded state is donated so XLA
+        # updates the cache pools in place instead of copying them every
+        # block/chunk.
         self._tick_block = jax.jit(tick_block,
-                                   donate_argnums=(0, 2, 3, 4, 5, 6))
-        self._chunk = jax.jit(chunk, donate_argnums=(0,))
+                                   donate_argnums=(1, 3, 4, 5, 6, 7))
+        self._chunk = jax.jit(chunk, donate_argnums=(1,))
         self._finalize = jax.jit(finalize, donate_argnums=(0, 1, 2))
 
     # ----------------------------------------------------------- scheduling
@@ -219,7 +224,8 @@ class PagedServingEngine:
         tokens = jnp.asarray(tokens)
         self.stats_counters["bytes_to_device"] += int(tokens.nbytes)
         self.kv.cache, logits = self._chunk(
-            self.kv.cache, self.kv.table, jnp.asarray(slots), tokens,
+            self.params, self.kv.cache, self.kv.table, jnp.asarray(slots),
+            tokens,
             jnp.asarray(starts), members[0][1].frames)
         self.stats_counters["prefill_chunks"] += len(members)
         for i, (slot, st) in enumerate(members):
@@ -277,8 +283,9 @@ class PagedServingEngine:
                 window_t0 = time.time()
                 (self.kv.cache, self.last_token, self.pos, self.remaining,
                  self.out_buf, self.out_cnt) = self._tick_block(
-                    self.kv.cache, self.kv.table, self.last_token, self.pos,
-                    self.remaining, self.out_buf, self.out_cnt)
+                    self.params, self.kv.cache, self.kv.table,
+                    self.last_token, self.pos, self.remaining, self.out_buf,
+                    self.out_cnt)
                 self.stats_counters["decode_ticks"] += K
                 ran_block = True
                 for slot in list(self._active):
@@ -476,7 +483,10 @@ class ContinuousBatcher:
 
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--arch", default="gemma-2b")
+    ap.add_argument("--arch", default="granite-moe-1b-a400m")
+    ap.add_argument("--full", action="store_true",
+                    help="use the registered full-width config, not the "
+                         "smoke one")
     ap.add_argument("--engine", choices=("paged", "dense"), default="paged")
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--max-new", type=int, default=16)
@@ -487,11 +497,10 @@ def main() -> None:
     ap.add_argument("--enc-len", type=int, default=8)
     args = ap.parse_args()
 
-    mod = importlib.import_module(
-        "repro.configs." + args.arch.replace("-", "_").replace(".", "_"))
-    cfg = mod.smoke()
+    enable_compile_cache()
+    cfg = get_config(args.arch) if args.full else smoke_config(args.arch)
     model = LanguageModel(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    params = jax.jit(model.init)(jax.random.PRNGKey(0))
     rng = np.random.RandomState(0)
     reqs = [Request(rid=i,
                     prompt=rng.randint(0, cfg.vocab_size, 8).tolist(),

@@ -22,6 +22,7 @@ from repro.data import TokenDataset
 from repro.distributed.sharding import MeshInfo, use_mesh_info
 from repro.models import LanguageModel
 from repro.optim import AdamW, OptConfig
+from repro.utils import enable_compile_cache
 
 
 def smoke_config(arch: str):
@@ -115,6 +116,7 @@ def main() -> None:
     ap.add_argument("--preempt-at", type=int, default=None)
     ap.add_argument("--partition", default="2024-01/all")
     args = ap.parse_args()
+    enable_compile_cache()
     out = train(arch=args.arch, smoke=not args.full, steps=args.steps,
                 global_batch=args.batch, seq_len=args.seq, peak_lr=args.lr,
                 ckpt_dir=args.ckpt_dir, save_every=args.save_every,

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.distributed.sharding import constrain
+from repro.models import moe as moe_mod
 from repro.models import transformer as tfm
 from repro.models.attention import ModelCtx
 from repro.models.layers import (Param, apply_norm, embed_init, init_norm,
@@ -154,15 +155,15 @@ class LanguageModel:
         return jax.tree.map(cast, params)
 
     def _backbone(self, params: dict, x: jax.Array, caches: Any,
-                  ctx: ModelCtx) -> tuple[jax.Array, Any, jax.Array]:
+                  ctx: ModelCtx) -> tuple[jax.Array, Any, dict]:
         new_caches = {} if caches is not None else None
-        aux = jnp.zeros((), jnp.float32)
+        aux = moe_mod.zero_stats()
         axes = self.param_axes
         for i, seg in enumerate(self.dec_segments):
             c = None if caches is None else caches[f"seg{i}"]
             x, nc, a = tfm.apply_segment(params[f"seg{i}"], self.cfg, seg, x,
                                          c, ctx, axes=axes.get(f"seg{i}"))
-            aux = aux + a
+            aux = jax.tree.map(jnp.add, aux, a)
             if new_caches is not None:
                 new_caches[f"seg{i}"] = nc
         return x, new_caches, aux
@@ -196,8 +197,9 @@ class LanguageModel:
         nll = (lse - label_logit) * weights
         denom = jnp.maximum(weights.sum(), 1.0)
         loss = nll.sum() / denom
-        total = loss + cfg.router_aux_coef * aux
-        metrics = {"loss": loss, "aux_loss": aux, "tokens": denom,
+        total = loss + cfg.router_aux_coef * aux["load_balance"]
+        metrics = {"loss": loss, "aux_loss": aux["load_balance"],
+                   "moe_dropped": aux["dropped"], "tokens": denom,
                    "total_loss": total}
         return total, metrics
 

@@ -19,30 +19,24 @@ through), matching GShard semantics.
 from __future__ import annotations
 
 import functools
-import inspect
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.distributed.sharding import constrain, current_mesh_info, shard_map_specs
 from repro.models.layers import Param, dense_init
 
-try:  # jax >= 0.6 moved shard_map to the top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-from jax.sharding import PartitionSpec as P
-
-# jax >= 0.6 renamed check_rep -> check_vma; disable either way (the dispatch
-# body's psum_scatter/all_gather pattern defeats the replication checker)
-_SM_NOCHECK = (
-    {"check_vma": False}
-    if "check_vma" in inspect.signature(_shard_map).parameters
-    else {"check_rep": False})
-
 _SMALL_T = 4096  # global token threshold below which dense path wins
+
+
+def zero_stats() -> dict:
+    """A layer's MoE statistics, summed over layers: the router's
+    load-balance loss and the (token, expert) assignments dropped for lack of
+    capacity (always 0 on the dense path)."""
+    return {"load_balance": jnp.zeros((), jnp.float32),
+            "dropped": jnp.zeros((), jnp.float32)}
 
 
 def init_moe(key: jax.Array, cfg: ModelConfig) -> dict:
@@ -85,7 +79,7 @@ def _route(router_w: jax.Array, x2d: jax.Array, cfg: ModelConfig):
 # ---------------------------------------------------------------------------
 
 
-def _moe_dense(p: dict, cfg: ModelConfig, x: jax.Array) -> tuple[jax.Array, jax.Array]:
+def _moe_dense(p: dict, cfg: ModelConfig, x: jax.Array) -> tuple[jax.Array, dict]:
     cdt = cfg.compute_dtype
     B, S, d = x.shape
     x2d = x.reshape(-1, d)
@@ -101,7 +95,7 @@ def _moe_dense(p: dict, cfg: ModelConfig, x: jax.Array) -> tuple[jax.Array, jax.
     sel = jax.nn.one_hot(idx, cfg.n_experts, dtype=cdt)  # (T, K, E)
     w_comb = jnp.einsum("tk,tke->te", gates.astype(cdt), sel)  # (T, E)
     y = jnp.einsum("te,ted->td", w_comb, y_e)
-    return y.reshape(B, S, d), aux
+    return y.reshape(B, S, d), {**zero_stats(), "load_balance": aux}
 
 
 # ---------------------------------------------------------------------------
@@ -125,7 +119,7 @@ def _dispatch_compute_combine(
     data_axis: str | None,
     model_axis: str,
     all_axes: tuple,
-) -> tuple[jax.Array, jax.Array]:
+) -> tuple[jax.Array, dict]:
     cdt = cfg.compute_dtype
     b_l, s_l, d = x_l.shape
     E = cfg.n_experts
@@ -170,11 +164,13 @@ def _dispatch_compute_combine(
     picked = jnp.where(keep[:, None], picked, 0)
     out = (picked.reshape(t_l, cfg.top_k, d)
            * gates.astype(cdt)[..., None]).sum(axis=1)
-    aux = jax.lax.pmean(aux, all_axes)
-    return out.reshape(b_l, s_l, d), aux
+    stats = {"load_balance": jax.lax.pmean(aux, all_axes),
+             "dropped": jax.lax.psum(jnp.sum(~keep).astype(jnp.float32),
+                                     all_axes)}
+    return out.reshape(b_l, s_l, d), stats
 
 
-def _moe_shard_map(p: dict, cfg: ModelConfig, x: jax.Array) -> tuple[jax.Array, jax.Array]:
+def _moe_shard_map(p: dict, cfg: ModelConfig, x: jax.Array) -> tuple[jax.Array, dict]:
     info = current_mesh_info()
     data_axes, model_axis = shard_map_specs(info)
     mesh = info.mesh
@@ -189,7 +185,9 @@ def _moe_shard_map(p: dict, cfg: ModelConfig, x: jax.Array) -> tuple[jax.Array, 
         model_axis=model_axis,
         all_axes=tuple(mesh.axis_names),
     )
-    out, aux = _shard_map(
+    # check_vma off: the dispatch body's all_gather/all_to_all pattern
+    # defeats the varying-manual-axes checker
+    out, stats = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(
@@ -200,9 +198,9 @@ def _moe_shard_map(p: dict, cfg: ModelConfig, x: jax.Array) -> tuple[jax.Array, 
             P("model", "data", None),  # w_down
         ),
         out_specs=(P(bs, "model", None), P()),
-        **_SM_NOCHECK,
+        check_vma=False,
     )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
-    return out, aux
+    return out, stats
 
 
 def _shard_map_viable(cfg: ModelConfig, x: jax.Array) -> bool:
@@ -218,7 +216,7 @@ def _shard_map_viable(cfg: ModelConfig, x: jax.Array) -> bool:
             and cfg.d_model % info.axis_size("data") == 0)
 
 
-def apply_moe(p: dict, cfg: ModelConfig, x: jax.Array) -> tuple[jax.Array, jax.Array]:
+def apply_moe(p: dict, cfg: ModelConfig, x: jax.Array) -> tuple[jax.Array, dict]:
     if _shard_map_viable(cfg, x):
         return _moe_shard_map(p, cfg, x)
     return _moe_dense(p, cfg, x)

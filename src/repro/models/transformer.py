@@ -153,9 +153,9 @@ def _active_mask(ctx: ModelCtx) -> jax.Array | None:
 
 
 def apply_layer(p: dict, cfg: ModelConfig, kind: LayerKind, x: jax.Array,
-                cache: Any, ctx: ModelCtx) -> tuple[jax.Array, Any, jax.Array]:
+                cache: Any, ctx: ModelCtx) -> tuple[jax.Array, Any, dict]:
     t, is_moe = kind
-    aux = jnp.zeros((), jnp.float32)
+    aux = moe_mod.zero_stats()
     h = apply_norm(p["norm1"], cfg, x)
 
     if t in ("attn", "swa"):
@@ -220,11 +220,11 @@ def init_superblock(key: jax.Array, cfg: ModelConfig,
 def apply_superblock(p: dict, cfg: ModelConfig, kinds: tuple[LayerKind, ...],
                      x: jax.Array, caches: Any, ctx: ModelCtx):
     new_caches = {}
-    aux = jnp.zeros((), jnp.float32)
+    aux = moe_mod.zero_stats()
     for i, kind in enumerate(kinds):
         c = None if caches is None else caches[f"sub{i}"]
         x, nc, a = apply_layer(p[f"sub{i}"], cfg, kind, x, c, ctx)
-        aux = aux + a
+        aux = jax.tree.map(jnp.add, aux, a)
         new_caches[f"sub{i}"] = nc
     return x, (None if caches is None else new_caches), aux
 
@@ -314,18 +314,25 @@ def apply_segment(p: Any, cfg: ModelConfig, seg: Segment, x: jax.Array,
             x_, aux_ = carry
             p_layer = _constrain_layer_params(p_layer, axes, scanned=True)
             x_, _, a = fn(p_layer, x=x_, caches=None)
-            return (x_, aux_ + a), None
+            return (x_, jax.tree.map(jnp.add, aux_, a)), None
 
-        (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), p)
+        (x, aux), _ = jax.lax.scan(body, (x, moe_mod.zero_stats()), p)
         return x, None, aux
 
+    # the stacked caches ride in the carry and each layer writes its slice
+    # back in place: as scan outputs they would be a second copy of every
+    # cache (a whole page pool per layer), which does not fit one chip
     def body(carry, xs):
-        x_, aux_ = carry
-        p_layer, cache_layer = xs
+        x_, aux_, caches_ = carry
+        i, p_layer = xs
         p_layer = _constrain_layer_params(p_layer, axes, scanned=True)
+        cache_layer = jax.tree.map(lambda c: c[i], caches_)
         x_, nc, a = fn(p_layer, x=x_, caches=cache_layer)
-        return (x_, aux_ + a), nc
+        caches_ = jax.tree.map(lambda c, n: c.at[i].set(n.astype(c.dtype)),
+                               caches_, nc)
+        return (x_, jax.tree.map(jnp.add, aux_, a), caches_), None
 
-    (x, aux), new_caches = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)), (p, caches))
+    (x, aux, new_caches), _ = jax.lax.scan(
+        body, (x, moe_mod.zero_stats(), caches),
+        (jnp.arange(seg.repeats), p))
     return x, new_caches, aux

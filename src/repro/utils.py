@@ -2,11 +2,32 @@
 from __future__ import annotations
 
 import math
+import os
+import pathlib
 import time
 from typing import Any, Iterator
 
 import jax
 import numpy as np
+
+
+#: fixed, git-ignored home of the persistent compile cache inside the
+#: checkout: a path that never changes lets a later run find what an
+#: earlier one compiled
+REPO_COMPILE_CACHE = pathlib.Path(__file__).resolve().parents[2] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is JAX's own setting and is left
+    alone; otherwise the cache goes to ``REPO_COMPILE_CACHE``.  Returns the
+    directory in use."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(REPO_COMPILE_CACHE))
+    return str(REPO_COMPILE_CACHE)
 
 
 def tree_size(tree: Any) -> int:

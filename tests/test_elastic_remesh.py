@@ -18,9 +18,11 @@ def run_sub(code: str, devices: int, timeout: int = 560) -> str:
             "--xla_force_host_platform_device_count={devices}")
         {textwrap.indent(textwrap.dedent(code), '        ').strip()}
     """)
+    # forced host devices are CPU devices: never let a child probe libtpu
     r = subprocess.run([sys.executable, "-c", prog], capture_output=True,
                        text=True, timeout=timeout,
-                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"})
+                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+                            "JAX_PLATFORMS": "cpu"})
     assert r.returncode == 0, f"OUT:{r.stdout[-2000:]}\nERR:{r.stderr[-3000:]}"
     return r.stdout
 
@@ -33,6 +35,7 @@ from repro.optim import AdamW, OptConfig
 from repro.checkpoint import CheckpointManager
 from repro.data import TokenDataset
 from repro.distributed.sharding import MeshInfo, use_mesh_info
+from repro.launch.mesh import make_mesh
 
 def build():
     cfg = smoke().scaled(compute_dtype="float32")
@@ -56,7 +59,7 @@ def test_elastic_shrink_matches_straight_run(tmp_path):
     # phase 1: 4 devices (2x2), 4 steps, save
     out1 = run_sub(TRAIN_SNIPPET + f"""
 cfg, model, opt, data = build()
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 info = MeshInfo(mesh)
 with use_mesh_info(info), mesh:
     params = jax.jit(model.init)(jax.random.PRNGKey(0))
@@ -78,7 +81,7 @@ print("PHASE1", float(loss))
     # phase 2: pool shrinks to 2 devices (2x1); resharded restore + 2 steps
     out2 = run_sub(TRAIN_SNIPPET + f"""
 cfg, model, opt, data = build()
-mesh = jax.make_mesh((2, 1), ("data", "model"))
+mesh = make_mesh((2, 1), ("data", "model"))
 info = MeshInfo(mesh)
 mgr = CheckpointManager({ck!r}, async_write=False)
 with use_mesh_info(info), mesh:

@@ -97,6 +97,21 @@ def test_linear_scan_kernel_vs_ref(case):
                                rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize("kernel", ["flash_attention", "linear_scan"])
+def test_kernels_refuse_silent_interpret_off_tpu(kernel):
+    """Off TPU, a kernel call that does not ask for interpret mode raises
+    instead of quietly running the Pallas interpreter."""
+    if jax.default_backend() == "tpu":
+        pytest.skip("the compiled kernel is the default on TPU")
+    if kernel == "flash_attention":
+        x = jnp.zeros((1, 8, 1, 8), jnp.float32)
+        args = (x, x, x)
+    else:
+        args = _wkv_inputs(1, 8, 1, 8)
+    with pytest.raises(ValueError, match="interpret=True"):
+        getattr(ops, kernel)(*args)
+
+
 def test_wkv_chunked_xla_path_vs_ref():
     """The XLA chunked-parallel path used in model code must match the oracle."""
     r, k, v, log_w, u, s0 = _wkv_inputs(2, 160, 2, 32, seed=3)
