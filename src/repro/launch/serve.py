@@ -34,6 +34,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.launch.paged_kv import PagedKVCache, decompose
+from repro.launch.spans import HloOp, Spans, hlo_ops
 from repro.launch.train import smoke_config
 from repro.models import LanguageModel
 from repro.utils import enable_compile_cache
@@ -50,6 +51,10 @@ class Request:
     rejected: bool = False
     admit_tick: int = -1
     finish_tick: int = -1
+    # the paged engine's stamps, on time.perf_counter(): admitted to a
+    # slot, and prefill finished (its finalize dispatched)
+    t_admit: float | None = None
+    t_prefilled: float | None = None
 
 
 # ---------------------------------------------------------------------------
@@ -74,6 +79,14 @@ class PagedServingEngine:
     decoding requests.  Output tokens accumulate in a device ring and drain
     to the host once per block; freed slots are recycled at drain
     boundaries.
+
+    The engine records its own host spans (``self.spans``: ``admit``,
+    ``prefill_round`` with a ``finalize`` per finished request,
+    ``decode_block`` from dispatch until its tokens are ready, ``drain``
+    with ``fetch`` and ``deliver``, and ``gc`` for each collection during
+    ``run``), stamps each ``Request`` on the same clock, and keeps the
+    abstract arguments of every program signature it dispatched, so that
+    ``hlo_ops`` can name the scope of each device operation.
     """
 
     def __init__(self, model: LanguageModel, params, n_slots: int = 64,
@@ -115,7 +128,10 @@ class PagedServingEngine:
             "drains": 0, "prefill_chunks": 0, "decode_ticks": 0,
             "stall_ticks": 0,
         }
-        self._window_walls: list[float] = []  # (wall_s, ticks) per drain gap
+        self.spans = Spans()
+        # (program, shapes that vary) -> abstract arguments of its first
+        # dispatch, for hlo_ops
+        self._signatures: dict[tuple, tuple] = {}
 
         def tick_block(params, cache, table, last, pos, remaining, out_buf,
                        out_cnt):
@@ -128,13 +144,14 @@ class PagedServingEngine:
                 pos_eff = jnp.where(emit, pos, -1)
                 logits, cache = model.decode_step(params, last[:, None],
                                                   cache, pos_eff, table=table)
-                nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-                b = jnp.arange(B)
-                # emit the *input* token (seed semantics: the first emitted
-                # token is the post-prefill argmax); inactive columns land
-                # OOB -> dropped
-                col = jnp.where(emit, out_cnt, drain_every)
-                out_buf = out_buf.at[b, col].set(last)
+                with jax.named_scope("sample"):
+                    nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+                    b = jnp.arange(B)
+                    # emit the *input* token (seed semantics: the first
+                    # emitted token is the post-prefill argmax); inactive
+                    # columns land OOB -> dropped
+                    col = jnp.where(emit, out_cnt, drain_every)
+                    out_buf = out_buf.at[b, col].set(last)
                 inc = emit.astype(jnp.int32)
                 return (cache, jnp.where(emit, nxt, last), pos + inc,
                         remaining - inc, out_buf, out_cnt + inc), None
@@ -148,16 +165,19 @@ class PagedServingEngine:
             """One batched prefill round: G slots advance one chunk each.
             Padded group entries (slot == n_slots, start == -1) gather init
             values, compute garbage, and scatter out of bounds -> dropped."""
-            rows = jnp.take(table, slots, axis=0, mode="fill",
-                            fill_value=self.kv.n_pages)
-            view = self.kv._gather_impl(cache, rows, slots)
+            with jax.named_scope("kv_pool"):
+                rows = jnp.take(table, slots, axis=0, mode="fill",
+                                fill_value=self.kv.n_pages)
+                view = self.kv._gather_impl(cache, rows, slots)
             batch = {"tokens": tokens}
             if frames is not None:
                 batch["frames"] = frames
             logits, view = model.prefill_chunk(params, batch, view, start)
-            cache = self.kv._scatter_impl(cache, view, rows, slots)
+            with jax.named_scope("kv_pool"):
+                cache = self.kv._scatter_impl(cache, view, rows, slots)
             return cache, logits
 
+        @jax.named_scope("sample")
         def finalize(last, pos, remaining, logits, slot, plen, max_new):
             tok = jnp.argmax(logits[0]).astype(jnp.int32)
             return (last.at[slot].set(tok), pos.at[slot].set(plen),
@@ -172,6 +192,27 @@ class PagedServingEngine:
                                    donate_argnums=(1, 3, 4, 5, 6, 7))
         self._chunk = jax.jit(chunk, donate_argnums=(1,))
         self._finalize = jax.jit(finalize, donate_argnums=(0, 1, 2))
+        # by name, for hlo_ops: the attributes above may be wrapped
+        self._jitted = {f.__name__: f for f in (
+            self._tick_block, self._chunk, self._finalize)}
+
+    def _note(self, program: str, key, args: tuple) -> None:
+        """Keep the abstract arguments of a program's first dispatch with
+        this ``key`` (its shapes that vary), for ``hlo_ops``."""
+        if (program, key) not in self._signatures:
+            self._signatures[(program, key)] = jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), args)
+
+    def hlo_ops(self) -> dict[str, list[list[HloOp]]]:
+        """The operations of every executable dispatched so far, per
+        program (``tick_block``, ``chunk``, ``finalize``): one list per
+        signature, each op with its named scope.  The executables come from
+        JAX's in-memory cache of what already ran, so nothing is compiled."""
+        out: dict[str, list[list[HloOp]]] = {}
+        for (program, _), args in self._signatures.items():
+            text = self._jitted[program].lower(*args).compile().as_text()
+            out.setdefault(program, []).append(hlo_ops(text))
+        return out
 
     # ----------------------------------------------------------- scheduling
     def _admit(self, queue: collections.deque[Request], now: int) -> None:
@@ -182,6 +223,8 @@ class PagedServingEngine:
                       if self.slot_req[s] is None]
         if not free_slots:
             return
+        sp = self.spans.open("admit")
+        t = time.perf_counter()
         keep: list[Request] = []
         while queue:
             req = queue.popleft()
@@ -195,10 +238,13 @@ class PagedServingEngine:
                 self.kv.alloc(slot, need)
                 self.slot_req[slot] = req
                 req.admit_tick = now
+                req.t_admit = t
+                sp.rids.append(req.rid)
                 self._pf[slot] = _Prefilling(req=req, start=0)
             else:
                 keep.append(req)
         queue.extend(keep)
+        self.spans.close(sp)
 
     def _prefill_step(self) -> None:
         """One batched prefill round: the oldest prefilling request picks the
@@ -214,6 +260,9 @@ class PagedServingEngine:
         ][:self.prefill_group]
 
         G = self.prefill_group
+        sp = self.spans.open("prefill_round",
+                             [st.req.rid for _, st in members], chunk=c,
+                             tokens=c * len(members))  # c fits each member
         tokens = np.zeros((G, c), np.int32)
         starts = np.full((G,), -1, np.int32)
         slots = np.full((G,), self.n_slots, np.int32)  # pad -> OOB drop
@@ -223,30 +272,40 @@ class PagedServingEngine:
             slots[i] = slot
         tokens = jnp.asarray(tokens)
         self.stats_counters["bytes_to_device"] += int(tokens.nbytes)
-        self.kv.cache, logits = self._chunk(
-            self.params, self.kv.cache, self.kv.table, jnp.asarray(slots),
-            tokens,
-            jnp.asarray(starts), members[0][1].frames)
+        frames = members[0][1].frames
+        args = (self.params, self.kv.cache, self.kv.table,
+                jnp.asarray(slots), tokens, jnp.asarray(starts), frames)
+        self._note("chunk", (c, frames is None), args)
+        self.kv.cache, logits = self._chunk(*args)
         self.stats_counters["prefill_chunks"] += len(members)
         for i, (slot, st) in enumerate(members):
             st.start += c
             if st.start >= len(st.req.prompt):
-                del self._pf[slot]
-                self.last_token, self.pos, self.remaining = self._finalize(
-                    self.last_token, self.pos, self.remaining, logits[i][None],
-                    jnp.asarray(slot, jnp.int32),
-                    jnp.asarray(len(st.req.prompt), jnp.int32),
-                    jnp.asarray(st.req.max_new, jnp.int32))
+                with self.spans("finalize", [st.req.rid]):
+                    del self._pf[slot]
+                    args = (self.last_token, self.pos, self.remaining,
+                            logits[i][None], jnp.asarray(slot, jnp.int32),
+                            jnp.asarray(len(st.req.prompt), jnp.int32),
+                            jnp.asarray(st.req.max_new, jnp.int32))
+                    self._note("finalize", (), args)
+                    self.last_token, self.pos, self.remaining = \
+                        self._finalize(*args)
+                    st.req.t_prefilled = time.perf_counter()
                 self._active.add(slot)
                 self._remaining_h[slot] = st.req.max_new
+        self.spans.close(sp)
 
     def _drain(self, now: int) -> None:
-        out_buf, out_cnt = jax.device_get((self.out_buf, self.out_cnt))
+        held = list(self._active | self._finished)
+        sp = self.spans.open("drain", [self.slot_req[s].rid for s in held])
+        with self.spans("fetch"):
+            out_buf, out_cnt = jax.device_get((self.out_buf, self.out_cnt))
+        deliver = self.spans.open("deliver")
         self.stats_counters["host_syncs"] += 1
         self.stats_counters["bytes_to_host"] += (
             int(self.out_buf.nbytes) + int(self.out_cnt.nbytes))
         self.stats_counters["drains"] += 1
-        for slot in list(self._active | self._finished):
+        for slot in held:
             req = self.slot_req[slot]
             req.out.extend(int(t) for t in out_buf[slot, :out_cnt[slot]])
             if slot in self._finished or len(req.out) >= req.max_new:
@@ -258,19 +317,24 @@ class PagedServingEngine:
                 self._active.discard(slot)
                 self._finished.discard(slot)
         self.out_cnt = jnp.zeros_like(self.out_cnt)
+        self.spans.close(deliver)
+        self.spans.close(sp)
 
     # ------------------------------------------------------------------ run
     def run(self, requests: list[Request]) -> dict:
+        with self.spans.running():
+            return self._run(requests)
+
+    def _run(self, requests: list[Request]) -> dict:
         # re-entrant: a warm engine can serve successive traces (benchmarks
         # reuse one instance so jit compiles are paid once, not per run)
         self.stats_counters = dict.fromkeys(self.stats_counters, 0)
-        self._window_walls = []
         pending = collections.deque(sorted(requests, key=lambda r: r.arrival))
         queue: collections.deque[Request] = collections.deque()
         t0 = time.time()
         ticks = 0
         ran_block = False
-        window_t0 = t0
+        block_s: list[float] = []  # each decode_block span's length
         K = self.drain_every
         while (pending or queue or self._active or self._finished
                or self._pf):
@@ -279,13 +343,18 @@ class PagedServingEngine:
             self._admit(queue, ticks)
 
             if self._active:
-                # one device-resident block: K decode ticks, zero host reads
-                window_t0 = time.time()
+                # one device-resident block: K decode ticks, zero host
+                # reads; its span runs until the block's tokens are ready
+                block = self.spans.open(
+                    "decode_block", [self.slot_req[s].rid
+                                     for s in self._active],
+                    active=len(self._active), ticks=K)
+                args = (self.params, self.kv.cache, self.kv.table,
+                        self.last_token, self.pos, self.remaining,
+                        self.out_buf, self.out_cnt)
+                self._note("tick_block", (), args)
                 (self.kv.cache, self.last_token, self.pos, self.remaining,
-                 self.out_buf, self.out_cnt) = self._tick_block(
-                    self.params, self.kv.cache, self.kv.table,
-                    self.last_token, self.pos, self.remaining, self.out_buf,
-                    self.out_cnt)
+                 self.out_buf, self.out_cnt) = self._tick_block(*args)
                 self.stats_counters["decode_ticks"] += K
                 ran_block = True
                 for slot in list(self._active):
@@ -313,13 +382,13 @@ class PagedServingEngine:
 
             idle = not self._active and not self._pf
             if ran_block or (idle and self._finished):
-                # window = block dispatch -> everything flushed, so the
+                # a block's span: dispatch -> everything flushed, so the
                 # tick_ms percentiles include interleaved prefill work (the
                 # interference being measured) but not host-side admission
                 self.last_token.block_until_ready()
-                now = time.time()
                 if ran_block:
-                    self._window_walls.append((now - window_t0, K))
+                    self.spans.close(block)
+                    block_s.append(block.t1 - block.t0)
                 self._drain(ticks)
                 ran_block = False
             elif (queue and not self._active and not self._pf
@@ -335,7 +404,7 @@ class PagedServingEngine:
         toks = sum(len(r.out) for r in served)
         lat = sorted((r.finish_tick - r.arrival) for r in served
                      if r.finish_tick >= 0)
-        per_tick = sorted(w / n for w, n in self._window_walls if n)
+        per_tick = sorted(w / K for w in block_s)
         stats = {
             "engine": "paged",
             "requests": len(requests),

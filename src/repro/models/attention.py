@@ -336,6 +336,7 @@ def paged_kv_cache_specs(n_pages: int, page_size: int, n_kv: int, dk: int,
     }
 
 
+@jax.named_scope("kv_pool")
 def paged_append(cache: dict, k_t: jax.Array, v_t: jax.Array, pos: jax.Array,
                  table: jax.Array) -> dict:
     """Append one token per slot into the page pool (decode).

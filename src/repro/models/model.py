@@ -92,6 +92,7 @@ class LanguageModel:
         return jax.eval_shape(self.init, jax.random.PRNGKey(0))
 
     # ------------------------------------------------------------- embeddings
+    @jax.named_scope("embed")
     def _embed(self, params: dict, tokens: jax.Array,
                embeds: jax.Array | None = None) -> jax.Array:
         cfg = self.cfg
@@ -106,6 +107,7 @@ class LanguageModel:
             x = apply_norm(params["embed_ln"], cfg, x)
         return constrain(x, "batch", "seq_act", None)
 
+    @jax.named_scope("lm_head")
     def _head(self, params: dict, x: jax.Array) -> jax.Array:
         cfg = self.cfg
         x = apply_norm(params["final_norm"], cfg, x)

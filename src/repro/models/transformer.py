@@ -152,55 +152,68 @@ def _active_mask(ctx: ModelCtx) -> jax.Array | None:
     return None
 
 
+def _mixer_scope(cfg: ModelConfig, t: str) -> str:
+    """The named scope of a layer's token mixer (profiles and op metadata)."""
+    if t in ("rglru", "rwkv6"):
+        return "recurrent"
+    return "mla" if cfg.use_mla and t in ("attn", "swa") else "attn"
+
+
 def apply_layer(p: dict, cfg: ModelConfig, kind: LayerKind, x: jax.Array,
                 cache: Any, ctx: ModelCtx) -> tuple[jax.Array, Any, dict]:
     t, is_moe = kind
     aux = moe_mod.zero_stats()
     h = apply_norm(p["norm1"], cfg, x)
 
-    if t in ("attn", "swa"):
-        window = cfg.window if t == "swa" else 0
-        if cfg.use_mla:
-            y, new_cache = mla_mod.apply_mla(p["core"], cfg, h, ctx, cache)
+    with jax.named_scope(_mixer_scope(cfg, t)):
+        if t in ("attn", "swa"):
+            window = cfg.window if t == "swa" else 0
+            if cfg.use_mla:
+                y, new_cache = mla_mod.apply_mla(p["core"], cfg, h, ctx,
+                                                 cache)
+            else:
+                # Only full-attention layers page; the flag (not cache
+                # structure sniffing) decides, because inside a scanned
+                # segment the cache is a tracer whose paged-ness can't be
+                # inspected.
+                paged = (ctx.table is not None and t == "attn"
+                         and ctx.mode == "decode")
+                y, new_cache = attn_mod.apply_attention(
+                    p["core"], cfg, h, ctx, cache, window=window, paged=paged)
+        elif t == "xattn":
+            y, self_c = attn_mod.apply_attention(
+                p["core"], cfg, h, ctx,
+                None if cache is None else cache["self"])
+            x = x + y
+            hx = apply_norm(p["norm_x"], cfg, x)
+            y, cross_c = attn_mod.apply_attention(
+                p["cross"], cfg, hx, ctx,
+                None if cache is None else cache["cross"], cross=True)
+            new_cache = (None if cache is None
+                         else {"self": self_c, "cross": cross_c})
+        elif t == "rglru":
+            y, new_cache = rec_mod.apply_rglru(p["core"], cfg, h, cache,
+                                               ctx.mode,
+                                               active=_active_mask(ctx))
+        elif t == "rwkv6":
+            y, new_cache = rec_mod.apply_rwkv_time_mix(
+                p["core"], cfg, h, cache, ctx.mode, active=_active_mask(ctx))
         else:
-            # Only full-attention layers page; the flag (not cache structure
-            # sniffing) decides, because inside a scanned segment the cache is
-            # a tracer whose paged-ness can't be inspected.
-            paged = (ctx.table is not None and t == "attn"
-                     and ctx.mode == "decode")
-            y, new_cache = attn_mod.apply_attention(p["core"], cfg, h, ctx,
-                                                    cache, window=window,
-                                                    paged=paged)
-    elif t == "xattn":
-        y, self_c = attn_mod.apply_attention(
-            p["core"], cfg, h, ctx, None if cache is None else cache["self"])
-        x = x + y
-        hx = apply_norm(p["norm_x"], cfg, x)
-        y, cross_c = attn_mod.apply_attention(
-            p["cross"], cfg, hx, ctx,
-            None if cache is None else cache["cross"], cross=True)
-        new_cache = None if cache is None else {"self": self_c, "cross": cross_c}
-    elif t == "rglru":
-        y, new_cache = rec_mod.apply_rglru(p["core"], cfg, h, cache, ctx.mode,
-                                           active=_active_mask(ctx))
-    elif t == "rwkv6":
-        y, new_cache = rec_mod.apply_rwkv_time_mix(
-            p["core"], cfg, h, cache, ctx.mode, active=_active_mask(ctx))
-    else:
-        raise ValueError(t)
+            raise ValueError(t)
     x = x + y
 
     h = apply_norm(p["norm2"], cfg, x)
-    if t == "rwkv6":
-        y, new_cache = rec_mod.apply_rwkv_channel_mix(
-            p["mlp"], cfg, h, new_cache, ctx.mode,
-            active=_active_mask(ctx))
-    elif is_moe:
-        y, aux = moe_mod.apply_moe(p["moe"], cfg, h)
-        if cfg.n_shared_experts:
-            y = y + apply_mlp(p["shared"], cfg, h)
-    else:
-        y = apply_mlp(p["mlp"], cfg, h)
+    with jax.named_scope("moe" if is_moe and t != "rwkv6" else "mlp"):
+        if t == "rwkv6":
+            y, new_cache = rec_mod.apply_rwkv_channel_mix(
+                p["mlp"], cfg, h, new_cache, ctx.mode,
+                active=_active_mask(ctx))
+        elif is_moe:
+            y, aux = moe_mod.apply_moe(p["moe"], cfg, h)
+            if cfg.n_shared_experts:
+                y = y + apply_mlp(p["shared"], cfg, h)
+        else:
+            y = apply_mlp(p["mlp"], cfg, h)
     x = x + y
     return x, new_cache, aux
 
@@ -326,10 +339,12 @@ def apply_segment(p: Any, cfg: ModelConfig, seg: Segment, x: jax.Array,
         x_, aux_, caches_ = carry
         i, p_layer = xs
         p_layer = _constrain_layer_params(p_layer, axes, scanned=True)
-        cache_layer = jax.tree.map(lambda c: c[i], caches_)
+        with jax.named_scope("kv_pool"):
+            cache_layer = jax.tree.map(lambda c: c[i], caches_)
         x_, nc, a = fn(p_layer, x=x_, caches=cache_layer)
-        caches_ = jax.tree.map(lambda c, n: c.at[i].set(n.astype(c.dtype)),
-                               caches_, nc)
+        with jax.named_scope("kv_pool"):
+            caches_ = jax.tree.map(
+                lambda c, n: c.at[i].set(n.astype(c.dtype)), caches_, nc)
         return (x_, jax.tree.map(jnp.add, aux_, a), caches_), None
 
     (x, aux, new_caches), _ = jax.lax.scan(
