@@ -157,32 +157,38 @@ def _resolve_scan(r, k, v, log_w, u, s0, *, interpret):
 
 
 def paged_attention(q, kp, vp, posp, table, pos_q, *, causal=True, window=0,
-                    scale=None):
+                    scale=None, layer=None, dtype=None):
     """Decode attention over a paged KV pool.
 
-    q: (B, 1, Hq, Dk); kp/vp: (n_pages, page_size, Hkv, D) pools;
+    q: (B, 1, Hq, Dk); kp/vp: (n_pages, page_size, Hkv * D) pools;
     posp: (n_pages, page_size) absolute positions (-1 = empty);
     table: (B, max_pages) block table, entries == n_pages = unallocated.
+    With ``layer`` the pools are stacked, (layers, n_pages, ...), and the
+    pages are gathered from that layer alone; ``dtype`` is what the gathered
+    keys and values are cast to.
 
     Gathers each slot's pages into a contiguous (B, max_pages*page_size, ...)
-    view — unallocated pages read as pos == -1 via take's fill mode, so the
-    position mask in ``attention_core`` drops them exactly.  The gather is
+    view — unallocated pages read as pos == -1 via the gather's fill mode, so
+    the position mask in ``attention_core`` drops them exactly.  The gather is
     O(B * max_pages * page_size), i.e. per-slot *capacity*, not pool size:
     slots only ever pay for the pages their own request reserved.
     """
-    import jax.numpy as jnp
-
     from repro.models.attention import attention_core  # lazy: avoid cycle
 
     B, P = table.shape
-    ps = kp.shape[1]
+    ps = posp.shape[-1]
     flat = table.reshape(-1)  # (B*P,)
-    k = jnp.take(kp, flat, axis=0, mode="fill", fill_value=0)
-    v = jnp.take(vp, flat, axis=0, mode="fill", fill_value=0)
-    pos_k = jnp.take(posp, flat, axis=0, mode="fill", fill_value=-1)
-    k = k.reshape(B, P * ps, *kp.shape[2:])
-    v = v.reshape(B, P * ps, *vp.shape[2:])
-    pos_k = pos_k.reshape(B, P * ps)
+    at = flat if layer is None else (layer, flat)
+
+    def gather(pool, fill, *heads):
+        g = pool.at[at].get(mode="fill", fill_value=fill)  # (B*P, ps, ...)
+        return g.reshape(B, P * ps, *heads)
+
+    hkv = kp.shape[-1] // q.shape[-1]
+    k, v = gather(kp, 0, hkv, -1), gather(vp, 0, hkv, -1)
+    pos_k = gather(posp, -1)
+    if dtype is not None:
+        k, v = k.astype(dtype), v.astype(dtype)
     return attention_core(q, k, v, pos_q, pos_k, causal=causal,
                           window=window, scale=scale)
 

@@ -11,7 +11,8 @@ ragged *and* exact.
 Layout
 ------
 Per full-attention layer the pool leaves are ``k``/``v``:
-``(n_pages, page_size, H, D)`` and ``pos``: ``(n_pages, page_size)`` (−1 =
+``(n_pages, page_size, H * D)`` (a token's heads are one row, so a page is
+one compact block of memory) and ``pos``: ``(n_pages, page_size)`` (−1 =
 empty).  A device-resident **block table** ``(n_slots, max_pages)`` maps each
 slot's logical pages to physical ones; unallocated entries hold ``n_pages``
 (one past the pool), which JAX scatter drops and ``jnp.take(mode="fill")``
@@ -160,7 +161,7 @@ class PagedKVCache:
         costs one jit trace per chunk length."""
         G = slots.shape[0]
 
-        def g(leaf, spec):
+        def g(leaf, spec, view):
             fill = -1 if leaf.dtype == jnp.int32 else 0
             pdim = _pages_dim(spec)
             if pdim is None:
@@ -169,11 +170,13 @@ class PagedKVCache:
                                 fill_value=fill)
             v = jnp.take(leaf, rows.reshape(-1), axis=pdim, mode="fill",
                          fill_value=fill)
-            shp = (v.shape[:pdim] + (G, self.max_pages * self.page_size)
-                   + v.shape[pdim + 2:])
+            # a pool's token row (H * D) opens into the view's (H, D)
+            shp = (v.shape[:pdim] + (G, self.view_len)
+                   + view[0].shape[pdim + 2:])
             return v.reshape(shp)
 
-        return jax.tree.map(g, cache, self.specs, is_leaf=_is_spec_leaf)
+        return jax.tree.map(g, cache, self.specs, self.view_specs,
+                            is_leaf=_is_spec_leaf)
 
     def _scatter_impl(self, cache, view, rows, slots):
         G = slots.shape[0]
@@ -187,7 +190,7 @@ class PagedKVCache:
                 return leaf.at[idx].set(v.astype(leaf.dtype))
             v = v.reshape(v.shape[:pdim]
                           + (G * self.max_pages, self.page_size)
-                          + v.shape[pdim + 2:])
+                          + leaf.shape[pdim + 2:])
             idx = (slice(None),) * pdim + (rows.reshape(-1),)
             # unallocated row entries == n_pages: out of bounds -> dropped
             return leaf.at[idx].set(v.astype(leaf.dtype))

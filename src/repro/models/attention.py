@@ -42,6 +42,10 @@ class ModelCtx:
     #: (not in the cache pytree) so scanned segments see it as a closure
     #: capture instead of a scanned leaf.
     table: jax.Array | None = None
+    #: () int32 index of the layer inside a scanned segment, set by the scan
+    #: body: paged pools then arrive stacked, (layers, n_pages, ...), and are
+    #: read and written at this layer in place.  None outside a scan.
+    layer: jax.Array | None = None
 
     @property
     def pos2d(self) -> jax.Array:
@@ -326,11 +330,16 @@ def paged_kv_cache_specs(n_pages: int, page_size: int, n_kv: int, dk: int,
                          dv: int, dtype) -> dict:
     """Specs for a page *pool*: no batch dim — physical pages are allocated
     to slots through a block table (see launch/paged_kv.py).  The ``pages``
-    logical tag is how gather/scatter code finds the pool dim."""
-    ax = ("pages", None, "kv_heads", None)
+    logical tag is how gather/scatter code finds the pool dim.
+
+    A token's heads are one row, ``(n_kv * d,)``.  Split as ``(n_kv, d)``
+    with a head size under the TPU's 128 lanes, the pool is either padded to
+    128 lanes a head or laid out pages-minor; in neither is a page one block
+    of memory that decode can gather, and write a token into, in place."""
+    ax = ("pages", None, "kv_heads" if kv_heads_shardable(n_kv) else None)
     return {
-        "k": (jax.ShapeDtypeStruct((n_pages, page_size, n_kv, dk), dtype), ax),
-        "v": (jax.ShapeDtypeStruct((n_pages, page_size, n_kv, dv), dtype), ax),
+        "k": (jax.ShapeDtypeStruct((n_pages, page_size, n_kv * dk), dtype), ax),
+        "v": (jax.ShapeDtypeStruct((n_pages, page_size, n_kv * dv), dtype), ax),
         "pos": (jax.ShapeDtypeStruct((n_pages, page_size), jnp.int32),
                 ("pages", None)),
     }
@@ -338,24 +347,34 @@ def paged_kv_cache_specs(n_pages: int, page_size: int, n_kv: int, dk: int,
 
 @jax.named_scope("kv_pool")
 def paged_append(cache: dict, k_t: jax.Array, v_t: jax.Array, pos: jax.Array,
-                 table: jax.Array) -> dict:
+                 table: jax.Array, layer: jax.Array | None = None) -> dict:
     """Append one token per slot into the page pool (decode).
 
     k_t: (B, 1, H, D); pos: (B,) absolute positions; table: (B, P).
     Slots with pos < 0 (inactive) and unallocated logical pages resolve to an
     out-of-bounds page index, so their scatter is dropped — a dead slot can
-    never corrupt pages that have been recycled to another request."""
-    n_pages, ps = cache["pos"].shape
+    never corrupt pages that have been recycled to another request.
+
+    With ``layer`` the pools are a scanned segment's stacked ones,
+    (layers, n_pages, ...), and the token lands at ``(layer, page, off)`` in
+    place.  The page stays its own index: a flat ``layer * n_pages + page``
+    would send the sentinel ``n_pages`` into the next layer's first page."""
+    n_pages, ps = cache["pos"].shape[-2:]
     P = table.shape[1]
     valid = (pos >= 0) & (pos < P * ps)
     lpage = jnp.clip(pos // ps, 0, P - 1)
     page = jnp.take_along_axis(table, lpage[:, None], axis=1)[:, 0]
     page = jnp.where(valid, page, n_pages)  # OOB scatter index -> dropped
     off = pos % ps
+    at = (page, off) if layer is None else (layer, page, off)
+
+    def row(t, pool):  # (B, 1, H, D) -> (B, H * D), the pool's token row
+        return t.reshape(t.shape[0], -1).astype(pool.dtype)
+
     return {
-        "k": cache["k"].at[page, off].set(k_t[:, 0].astype(cache["k"].dtype)),
-        "v": cache["v"].at[page, off].set(v_t[:, 0].astype(cache["v"].dtype)),
-        "pos": cache["pos"].at[page, off].set(pos),
+        "k": cache["k"].at[at].set(row(k_t, cache["k"])),
+        "v": cache["v"].at[at].set(row(v_t, cache["v"])),
+        "pos": cache["pos"].at[at].set(pos),
     }
 
 
@@ -435,12 +454,13 @@ def apply_attention(
         elif ctx.mode == "decode" and paged:
             # Page-pool cache: scatter the new token through the block table,
             # then attend over the slot's gathered pages (kernels/ops).
-            new_cache = paged_append(cache, k, v, ctx.cache_pos, ctx.table)
+            new_cache = paged_append(cache, k, v, ctx.cache_pos, ctx.table,
+                                     layer=ctx.layer)
             from repro.kernels import ops as kops
             o = kops.paged_attention(
-                q, new_cache["k"].astype(cdt), new_cache["v"].astype(cdt),
-                new_cache["pos"], ctx.table, pos_q, causal=ctx.causal,
-                window=window)
+                q, new_cache["k"], new_cache["v"], new_cache["pos"],
+                ctx.table, pos_q, causal=ctx.causal, window=window,
+                layer=ctx.layer, dtype=cdt)
         elif ctx.mode == "decode":
             new_cache = append_cache(cache, k, v, ctx.cache_pos)
             k_att = constrain(new_cache["k"], *kv_ax).astype(cdt)

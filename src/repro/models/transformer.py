@@ -159,6 +159,15 @@ def _mixer_scope(cfg: ModelConfig, t: str) -> str:
     return "mla" if cfg.use_mla and t in ("attn", "swa") else "attn"
 
 
+def _paged(cfg: ModelConfig, kind: LayerKind, ctx: ModelCtx) -> bool:
+    """Whether a sub-layer decodes through a page pool.  Only full-attention
+    layers page; the rule (not cache structure sniffing) decides, because
+    inside a scanned segment the cache is a tracer whose paged-ness can't be
+    inspected."""
+    return (kind[0] == "attn" and not cfg.use_mla and ctx.mode == "decode"
+            and ctx.table is not None)
+
+
 def apply_layer(p: dict, cfg: ModelConfig, kind: LayerKind, x: jax.Array,
                 cache: Any, ctx: ModelCtx) -> tuple[jax.Array, Any, dict]:
     t, is_moe = kind
@@ -172,14 +181,9 @@ def apply_layer(p: dict, cfg: ModelConfig, kind: LayerKind, x: jax.Array,
                 y, new_cache = mla_mod.apply_mla(p["core"], cfg, h, ctx,
                                                  cache)
             else:
-                # Only full-attention layers page; the flag (not cache
-                # structure sniffing) decides, because inside a scanned
-                # segment the cache is a tracer whose paged-ness can't be
-                # inspected.
-                paged = (ctx.table is not None and t == "attn"
-                         and ctx.mode == "decode")
                 y, new_cache = attn_mod.apply_attention(
-                    p["core"], cfg, h, ctx, cache, window=window, paged=paged)
+                    p["core"], cfg, h, ctx, cache, window=window,
+                    paged=_paged(cfg, kind, ctx))
         elif t == "xattn":
             y, self_c = attn_mod.apply_attention(
                 p["core"], cfg, h, ctx,
@@ -332,19 +336,27 @@ def apply_segment(p: Any, cfg: ModelConfig, seg: Segment, x: jax.Array,
         (x, aux), _ = jax.lax.scan(body, (x, moe_mod.zero_stats()), p)
         return x, None, aux
 
-    # the stacked caches ride in the carry and each layer writes its slice
-    # back in place: as scan outputs they would be a second copy of every
-    # cache (a whole page pool per layer), which does not fit one chip
+    # the stacked caches ride in the carry: as scan outputs they would be a
+    # second copy of every cache (a whole page pool per layer), which does
+    # not fit one chip.  A paged sub-layer reads and writes its stacked pool
+    # in place at ctx.layer; every other cache is sliced out for the layer
+    # and its slice written back.
+    in_place = {f"sub{j}": _paged(cfg, kind, ctx)
+               for j, kind in enumerate(seg.kinds)}
+
     def body(carry, xs):
         x_, aux_, caches_ = carry
         i, p_layer = xs
         p_layer = _constrain_layer_params(p_layer, axes, scanned=True)
         with jax.named_scope("kv_pool"):
-            cache_layer = jax.tree.map(lambda c: c[i], caches_)
-        x_, nc, a = fn(p_layer, x=x_, caches=cache_layer)
+            cache_layer = {s: c if in_place[s] else jax.tree.map(
+                lambda a: a[i], c) for s, c in caches_.items()}
+        x_, nc, a = fn(p_layer, x=x_, caches=cache_layer,
+                       ctx=dataclasses.replace(ctx, layer=i))
         with jax.named_scope("kv_pool"):
-            caches_ = jax.tree.map(
-                lambda c, n: c.at[i].set(n.astype(c.dtype)), caches_, nc)
+            caches_ = {s: nc[s] if in_place[s] else jax.tree.map(
+                lambda c, n: c.at[i].set(n.astype(c.dtype)), c, nc[s])
+                for s, c in caches_.items()}
         return (x_, jax.tree.map(jnp.add, aux_, a), caches_), None
 
     (x, aux, new_caches), _ = jax.lax.scan(

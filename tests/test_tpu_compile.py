@@ -10,6 +10,8 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
 this file.  Keep these tests in this one file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -103,13 +105,12 @@ def test_granite_paged_decode_step_compiles_for_v5e(one_chip):
     assert 0 < used < V5E_HBM_BYTES, used
 
 
-@pytest.mark.parametrize("program", ["tick_block", "chunk"])
-def test_granite_serving_engine_programs_fit_v5e(one_chip, program):
-    """The engine's own decode block and its largest prefill chunk, as
-    ``chip_smoke.py`` serves granite (bf16 weights, 32 slots x 2048 tokens,
-    pages of 16).  The engine is built under ``jax.eval_shape``, so its
-    page pool exists only as shapes; the compiler refuses a program that
-    does not fit the chip's HBM."""
+def _granite_engine_program(one_chip, program):
+    """The serving engine's ``tick_block`` or its largest ``chunk``, lowered
+    for one described v5e as ``chip_smoke.py`` serves granite (bf16
+    weights, 32 slots x 2048 tokens, pages of 16), and the engine.  The
+    engine is built under ``jax.eval_shape``, so its page pool exists only
+    as shapes."""
     cfg = get_config("granite-moe-1b-a400m").scaled(param_dtype="bfloat16")
     model = LanguageModel(cfg)
     built = {}
@@ -129,13 +130,48 @@ def test_granite_serving_engine_programs_fit_v5e(one_chip, program):
     if program == "tick_block":
         state = placed((eng.last_token, eng.pos, eng.remaining, eng.out_buf,
                         eng.out_cnt))
-        lowered = eng._tick_block.lower(params, cache, table, *state)
-    else:
-        G, c = eng.prefill_group, eng.chunk_max
-        lowered = eng._chunk.lower(
-            params, cache, table, _sds((G,), jnp.int32, one_chip),
-            _sds((G, c), jnp.int32, one_chip),
-            _sds((G,), jnp.int32, one_chip), None)
+        return eng._tick_block.lower(params, cache, table, *state), eng
+    G, c = eng.prefill_group, eng.chunk_max
+    return eng._chunk.lower(
+        params, cache, table, _sds((G,), jnp.int32, one_chip),
+        _sds((G, c), jnp.int32, one_chip),
+        _sds((G,), jnp.int32, one_chip), None), eng
+
+
+@pytest.mark.parametrize("program", ["tick_block", "chunk"])
+def test_granite_serving_engine_programs_fit_v5e(one_chip, program):
+    """The engine's own decode block and its largest prefill chunk; the
+    compiler refuses a program that does not fit the chip's HBM."""
+    lowered, _ = _granite_engine_program(one_chip, program)
     mem = lowered.compile().memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert 0 < used < V5E_HBM_BYTES, used
+
+
+#: temp bytes of granite's ``tick_block`` when each decode layer copied its
+#: keys and values out of the stacked pools and wrote them back
+SLICED_TICK_BLOCK_TEMP_BYTES = 6718960640
+
+
+def test_granite_tick_block_decodes_in_place_in_stacked_pools(one_chip):
+    """The decode block reads and writes granite's stacked page pools,
+    ``bf16[24, 4096, 16, 512]``, at each layer in place: the token is
+    scattered into the stacked pool, and no dynamic-slice or
+    dynamic-update-slice copies one layer of a pool or a whole one (the
+    copy out and back that took half of every decode tick), nor does a
+    copy relayout a whole pool at the program's edge; so the block needs
+    less temp memory than that copy did."""
+    lowered, eng = _granite_engine_program(one_chip, "tick_block")
+    pool = eng.kv.cache["seg0"]["sub0"]["k"].shape
+    assert pool[0] == 24, pool
+    layer = ",".join(map(str, pool[1:]))
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    assert re.search(rf"= bf16\[{pool[0]},{layer}\]\S* scatter\(", hlo)
+    slices = re.findall(rf"= bf16\[(?:\d+,)?{layer}\]\S* "
+                        rf"(?:dynamic-slice|dynamic-update-slice)\(", hlo)
+    assert not slices, slices
+    copies = re.findall(rf"= bf16\[{pool[0]},{layer}\]\S* copy\(", hlo)
+    assert not copies, copies
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert 0 < temp < SLICED_TICK_BLOCK_TEMP_BYTES, temp
