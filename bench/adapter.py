@@ -1,5 +1,6 @@
 """The benchmark's one coupling to the program: build the served model from a
-configuration file, and drive ``PagedServingEngine.run`` as an open loop.
+configuration file (through its architecture module, ``bench/arch``), and
+drive ``PagedServingEngine.run`` as an open loop.
 
 ``PagedServingEngine`` has no wall-clock submit API: ``Request.arrival`` is
 in ticks and ``run()`` blocks until every request is done.  So every request
@@ -31,100 +32,25 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import arch as archs
 from bench import weights as W
 from bench.traffic import Traffic
 
-# Hugging Face key in a configuration file -> ModelConfig field
-_FIELDS = {
-    "num_hidden_layers": "n_layers", "hidden_size": "d_model",
-    "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv_heads",
-    "head_dim": "head_dim", "num_local_experts": "n_experts",
-    "num_experts_per_tok": "top_k", "vocab_size": "vocab_size",
-    "tie_word_embeddings": "tie_embeddings", "rope_theta": "rope_theta",
-    "rms_norm_eps": "norm_eps", "hidden_act": "act",
-}
-# what the reference computes; a program config that says otherwise is
-# another architecture
-_BLOCK = {"layer_pattern": ("attn",), "mlp_type": "glu", "pos_type": "rope",
-          "rope_fraction": 1.0, "use_mla": False, "n_shared_experts": 0,
-          "first_dense_layers": 0, "norm_type": "rmsnorm",
-          "gemma_norm": False, "emb_scale": False, "enc_dec": False,
-          "embed_norm": False}
 
-
-def program_config(cj: dict):
-    """The program's ``ModelConfig`` for a configuration file.  A size that
-    differs from the program's registered config must be in ``reduced``."""
-    from repro.configs import get_config
-
-    base = get_config(cj["program_config"])
-    m = cj["model"]
-    fields = dict(_FIELDS)
-    fields["intermediate_size"] = "d_ff_expert" if m["num_local_experts"] \
-        else "d_ff"
-    over = {}
-    for key, field in fields.items():
-        if getattr(base, field) != m[key]:
-            if key not in cj["reduced"]:
-                raise ValueError(f"{cj['name']}: {key} = {m[key]} in the file "
-                                 f"but {getattr(base, field)} in the program, "
-                                 f"and {key} is not in 'reduced'")
-            over[field] = m[key]
-    cfg = base.scaled(**over, param_dtype=cj["dtype"]["weights"],
-                      compute_dtype=cj["dtype"]["compute"])
-    for field, want in _BLOCK.items():
-        if getattr(cfg, field) != want:
-            raise ValueError(f"{cj['name']}: {field} = {getattr(cfg, field)}; "
-                             f"the reference computes {want}")
-    return cfg
-
-
-def _program_tree(model, m: dict, key, served):
-    """The program's parameter tree, filled from ``bench.weights``."""
-    layers = jax.vmap(lambda i: W.layer_weights(m, key, i, served, served))(
-        jnp.arange(m["num_hidden_layers"]))
-    g = W.global_weights(m, key, served, served)
-    d, h, hkv, hd = (m["hidden_size"], m["num_attention_heads"],
-                     m["num_key_value_heads"], m["head_dim"])
-    tree = {"embed": g["embed"], "final_norm": {"scale": g["final_norm"]}}
-    if not m["tie_word_embeddings"]:
-        tree["out"] = g["lm_head"]
-    first = 0
-    for i, seg in enumerate(model.dec_segments):
-        if len(seg.kinds) != 1:
-            raise ValueError(f"segment {i} mixes layer kinds: {seg.kinds}")
-        n = seg.n_layers
-        lw = jax.tree.map(lambda t: t[first:first + n], layers)
-        first += n
-        sub = {"norm1": {"scale": lw["attn_norm"]},
-               "norm2": {"scale": lw["mlp_norm"]},
-               "core": {"w_q": lw["wq"].reshape(n, d, h, hd),
-                        "w_k": lw["wk"].reshape(n, d, hkv, hd),
-                        "w_v": lw["wv"].reshape(n, d, hkv, hd),
-                        "w_o": lw["wo"].reshape(n, h, hd, d)}}
-        if W.is_moe(m):
-            sub["moe"] = {"router": lw["router"], "w_gate": lw["e_gate"],
-                          "w_up": lw["e_up"], "w_down": lw["e_down"]}
-        else:
-            sub["mlp"] = {"w_gate": lw["w_gate"], "w_up": lw["w_up"],
-                          "w_down": lw["w_down"]}
-        if not seg.scanned:
-            sub = jax.tree.map(lambda t: t[0], sub)
-        tree[f"seg{i}"] = {"sub0": sub}
-    return tree
-
-
-def make_engine(cj: dict, seed: int):
-    """Model, weights (one jitted call on the device) and engine."""
+def make_engine(cj: dict, seed: int, arch=None):
+    """Model, weights (one jitted call on the device) and engine.  ``arch``
+    is the configuration's architecture module (``bench/arch``), found by
+    the name the file gives where it is not passed."""
     from repro.launch.serve import PagedServingEngine
     from repro.models import LanguageModel
 
-    model = LanguageModel(program_config(cj))
+    arch = arch or archs.of(cj)
+    model = LanguageModel(arch.program_config(cj))
     m = cj["model"]
     served = jnp.dtype(cj["dtype"]["weights"])
 
     def build(key):
-        return _program_tree(model, m, key, served)
+        return arch.program_tree(model, m, key, served)
 
     key = W.seed_key(seed)
     want = model.abstract_params()

@@ -6,11 +6,11 @@
 In one process, for each seed, one run of the cell as ``bench/run.py``
 makes it (``run.run_cell``), whose sample is compared twice: as the program
 served it, and with the float8 control in the program's place.  One JSON
-line per seed: both verdicts with the numbers compared, and the run's
-end-to-end metrics.  The program's readings over a dozen seeds give each
-limit's lower end, the control's its upper end.  ``--fault`` plants a fault
-of ``bench/faults.py`` in the timed path.  The benchmark's own runs never
-run the control.
+line per seed: both verdicts with the numbers compared, the run's
+end-to-end metrics and its device.  The program's readings over a dozen
+seeds give each limit's lower end, the control's its upper end.
+``--fault`` plants a fault of ``bench/faults.py`` in the timed path.  The
+benchmark's own runs never run the control.
 """
 from __future__ import annotations
 
@@ -59,7 +59,8 @@ def main() -> int:
             "seconds": args.seconds, "correct": out["correct"],
             "checks": out["checks"], "control": out["control"],
             "metrics": {k: v["value"] for k, v in out["metrics"].items()},
-            "attempted": out["attempted"]}), flush=True)
+            "attempted": out["attempted"], "device": out["device"]}),
+            flush=True)
         t = time.perf_counter()
     return 0
 

@@ -2,7 +2,8 @@
 
 After the window, a sample of the requests the engine finished, drawn from
 the seed and always holding the longest, is run once through the plain
-reference (``bench/reference``) over its prompt and its served tokens.  At
+reference of the configuration's architecture (``Reference`` of
+``bench/arch/<name>.py``) over its prompt and its served tokens.  At
 every served token, the gap by which the reference's logit for that token
 lies below the reference's best is read; the widest gap of the sample is
 the number compared.  The engine decodes greedily, so a sound engine serves
@@ -15,7 +16,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from bench.reference.llama import Reference
+from bench import arch as archs
 
 # requests to compare: each is one greedy trajectory, and a random model's
 # trajectory soon settles into repeating a token by a wide margin, where no
@@ -39,9 +40,13 @@ def sample(finished: list, seed: int) -> list:
                         rng.permutation(len(rest))[:SAMPLE_REQUESTS - 1]]
 
 
-def compare(cj: dict, seed: int, reqs: list, control: bool = False) -> dict:
-    """Widest gap over ``reqs`` (and the float8 control's, if asked)."""
-    ref = Reference(cj["model"], seed, cj["dtype"]["weights"])
+def compare(cj: dict, seed: int, reqs: list, control: bool = False,
+            arch=None) -> dict:
+    """Widest gap over ``reqs`` (and the float8 control's, if asked).
+    ``arch`` is the configuration's architecture module, found by the name
+    the file gives where it is not passed."""
+    arch = arch or archs.of(cj)
+    ref = arch.Reference(cj["model"], seed, cj["dtype"]["weights"])
     max_len = cj["engine"]["max_len"]
     gaps, ctl = [], []
     for r in reqs:

@@ -29,7 +29,7 @@ from jax.sharding import SingleDeviceSharding  # noqa: E402
 def main(path: str) -> int:
     from jax.experimental import topologies
 
-    from bench import adapter
+    from bench import arch
     from repro.launch.serve import PagedServingEngine
     from repro.models import LanguageModel
 
@@ -38,7 +38,7 @@ def main(path: str) -> int:
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     chip = SingleDeviceSharding(topo.devices[0])
-    model = LanguageModel(adapter.program_config(cj))
+    model = LanguageModel(arch.of(cj, ROOT).program_config(cj))
     e = cj["engine"]
     built = {}
 

@@ -13,5 +13,5 @@ def read(ctx):
     need = sum(w.min_seconds(ctx.peaks.bf16_flops_per_s,
                              ctx.peaks.hbm_bytes_per_s)
                for block in ctx.loop.blocks
-               for w in counts.decode_block(ctx.m, block))
+               for w in counts.decode_block(ctx.arch, ctx.m, block))
     return 100.0 * need / (ns / 1e9)

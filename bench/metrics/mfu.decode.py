@@ -9,5 +9,5 @@ def read(ctx):
     if not ns or not ctx.loop.blocks:
         return None
     flops = sum(w.flops for block in ctx.loop.blocks
-                for w in counts.decode_block(ctx.m, block))
+                for w in counts.decode_block(ctx.arch, ctx.m, block))
     return 100.0 * flops / (ns / 1e9 * ctx.peaks.bf16_flops_per_s)

@@ -2,7 +2,6 @@
 the traced window over the device time of ``jit_chunk`` and
 ``jit_finalize`` x peak.  It bounds ``prefill_roofline`` from the step's
 side: a kernel taken off the path leaves its roofline silent, not this."""
-from bench import counts
 
 
 def read(ctx):
@@ -11,6 +10,6 @@ def read(ctx):
         if r is not None else 0.0
     if not ns or not ctx.loop.rounds:
         return None
-    flops = sum(counts.prefill_round(ctx.m, members).flops
+    flops = sum(ctx.arch.prefill_round(ctx.m, members).flops
                 for members in ctx.loop.rounds)
     return 100.0 * flops / (ns / 1e9 * ctx.peaks.bf16_flops_per_s)

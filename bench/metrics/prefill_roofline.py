@@ -2,7 +2,6 @@
 rounds of the traced window (max of FLOPs over peak and bytes over
 bandwidth, per round; MoE counted for its top-k experts only) over the
 device time of ``jit_chunk``."""
-from bench import counts
 
 
 def read(ctx):
@@ -10,7 +9,7 @@ def read(ctx):
     ns = r.program_ns("jit_chunk") if r is not None else 0.0
     if not ns or not ctx.loop.rounds:
         return None
-    need = sum(counts.prefill_round(ctx.m, members).min_seconds(
+    need = sum(ctx.arch.prefill_round(ctx.m, members).min_seconds(
         ctx.peaks.bf16_flops_per_s, ctx.peaks.hbm_bytes_per_s)
         for members in ctx.loop.rounds)
     return 100.0 * need / (ns / 1e9)

@@ -6,7 +6,7 @@ attention, a SiLU GLU MLP or a softmax top-k mixture of GLU experts with the
 chosen gates renormalised, a final RMSNorm and an LM head (tied or not).
 Every matmul runs at "highest" precision.  Weights come from
 ``bench.weights``, layer by layer, so the whole model never has to be
-resident in float32.
+resident in float32, with the tensors that ``bench/arch/llama.py`` names.
 
 ``fp8=True`` is the control: every linear layer's operands, weights (per
 output column) and activations (per row), are rounded to float8 e4m3 before
@@ -77,7 +77,7 @@ def _block(m: dict, fp8: bool, x: jax.Array, w: dict) -> jax.Array:
     x = x + _mm(o, w["wo"], fp8)
 
     a = _rms(x, w["mlp_norm"], eps)
-    if not W.is_moe(m):
+    if not m["num_local_experts"]:
         return x + _glu(a, w["w_gate"], w["w_up"], w["w_down"], fp8)
     e, k_top = m["num_local_experts"], m["num_experts_per_tok"]
     probs = jax.nn.softmax(_mm(a, w["router"], fp8), axis=-1)  # (S, E)
@@ -92,16 +92,19 @@ def _block(m: dict, fp8: bool, x: jax.Array, w: dict) -> jax.Array:
 
 
 class Reference:
-    """Forward passes of one configuration with one seed's weights."""
+    """Forward passes of one configuration with one seed's weights, of the
+    tensors ``layer_shapes`` and ``global_shapes`` describe."""
 
-    def __init__(self, m: dict, seed: int, served_dtype: str):
+    def __init__(self, m: dict, seed: int, served_dtype: str,
+                 layer_shapes: dict, global_shapes: dict):
         self.m = m
         self.key = W.seed_key(seed)
         served = jnp.dtype(served_dtype)
         self._layer = jax.jit(functools.partial(
-            W.layer_weights, m, served=served, dtype=jnp.float32))
+            W.layer_weights, layer_shapes, served=served, dtype=jnp.float32))
         self._globals = jax.jit(functools.partial(
-            W.global_weights, m, served=served, dtype=jnp.float32))
+            W.global_weights, global_shapes, served=served,
+            dtype=jnp.float32))
         self._blocks = {fp8: jax.jit(functools.partial(_block, m, fp8))
                         for fp8 in (False, True)}
         self._heads = {fp8: jax.jit(functools.partial(self._head_impl, fp8))

@@ -18,7 +18,8 @@ The last line of standard output is one JSON object: ``correct``,
 ``--trace 1``), and last ``checks``: each number compared, with its limit.
 
 Everything the run needs is found by name: the configuration file named in
-``BENCHMARK.json``, ``bench/traffic/<mix>.json`` and
+``BENCHMARK.json``, the architecture module ``bench/arch/<name>.py`` that
+the file names, ``bench/traffic/<mix>.json`` and
 ``bench/metrics/<metric>.py`` (a ``read(ctx)`` that returns a number, or
 None when it finds nothing to read).
 
@@ -53,6 +54,7 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 import jax  # noqa: E402
 
 from bench import adapter, correct, peaks, traffic  # noqa: E402
+from bench import arch as archs  # noqa: E402
 from bench import trace as trace_mod  # noqa: E402
 
 NO_DEVICE = 3
@@ -68,6 +70,7 @@ class Ctx:
     setup_s: float
     seconds: float
     drain_every: int
+    arch: object = None  # the architecture module (bench/arch): counts
 
 
 class CompileCounter:
@@ -99,7 +102,7 @@ def load_cell(root: str, workload: str):
     with open(os.path.join(root, entry["file"])) as f:
         cj = json.load(f)
     mix = traffic.load_mix(root, cell["traffic"])
-    return bench, cell, cj, mix
+    return bench, cell, cj, mix, archs.of(cj, root)
 
 
 def cell_metrics(bench: dict, cell: dict, trace: bool) -> list[dict]:
@@ -163,13 +166,14 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
     may alter the engine before its warm-up (``bench/faults.py``).  With
     ``control``, the result also holds the lower-precision control's
     verdict on the same sample (``bench/calibrate.py``)."""
-    bench, cell, cj, mix = load_cell(root, workload)
+    bench, cell, cj, mix, arch = load_cell(root, workload)
     devices = jax.devices()
     dev = devices[0]
     pk = peaks.peaks_for(dev.device_kind) if dev.platform == "tpu" else None
 
-    tr = traffic.make_traffic(mix, seconds, seed, cj["model"]["vocab_size"])
-    eng = adapter.make_engine(cj, seed)
+    eng = adapter.make_engine(cj, seed, arch)
+    tr = traffic.make_traffic(mix, seconds, seed,
+                              eng.model.cfg.vocab_size)
     if setup_hook is not None:
         setup_hook(eng)
     adapter.warm_up(eng)
@@ -206,7 +210,8 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
         shutil.rmtree(tmp, ignore_errors=True)
 
     ctx = Ctx(loop=loop, m=cj["model"], peaks=pk, reduced=reduced,
-              setup_s=setup_s, seconds=seconds, drain_every=eng.drain_every)
+              setup_s=setup_s, seconds=seconds, drain_every=eng.drain_every,
+              arch=arch)
     metrics = {}
     for spec in cell_metrics(bench, cell, trace):
         value = read_metric(root, spec["name"], ctx)
@@ -229,7 +234,7 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
     gc.collect()
 
     got = correct.compare(cj, seed, correct.sample(finished, seed),
-                          control=control)
+                          control=control, arch=arch)
     ok, checks = verdict(got, cj["correct"], compiled_in_window, unanswered)
 
     device = {"platform": dev.platform, "kind": dev.device_kind,
@@ -282,7 +287,7 @@ def main(argv=None) -> int:
                          "rehearsal for tests, never a measurement")
     args = ap.parse_args(argv)
 
-    _, cell, _, _ = load_cell(ROOT, args.workload)
+    _, cell, _, _, _ = load_cell(ROOT, args.workload)
     devices = jax.devices()
     if devices[0].platform != "tpu" and not args.cpu_rehearsal:
         print(f"bench: JAX found no TPU (device 0 is "

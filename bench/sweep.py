@@ -8,9 +8,10 @@ For each rate (the starting population and the pre-roll follow the rate, as
 ``bench/traffic.py`` derives them), one JSON line: requests offered in the
 window, the backlog (due and no first token yet) at the window's opening
 and at its end, the slots decoding at both, and the window's end-to-end
-readings.  The knee is the highest rate at which the backlog at the end is
-no longer than at the opening.  The benchmark's cells offer a fixed rate
-found this way; they never search.
+readings (with the mean time per output token, from which a mix's
+``decode_s`` is derived).  The knee is the highest rate at which the
+backlog at the end is no longer than at the opening.  The benchmark's cells
+offer a fixed rate found this way; they never search.
 """
 from __future__ import annotations
 
@@ -35,11 +36,11 @@ import jax  # noqa: E402
 from bench import adapter, run, traffic  # noqa: E402
 
 
-def window_at(cj: dict, mix: dict, rate: float, seed: int,
+def window_at(cj: dict, arch, mix: dict, rate: float, seed: int,
               seconds: float) -> dict:
     mix = {**mix, "arrivals": {**mix["arrivals"], "rate_per_s": rate}}
-    tr = traffic.make_traffic(mix, seconds, seed, cj["model"]["vocab_size"])
-    eng = adapter.make_engine(cj, seed)
+    eng = adapter.make_engine(cj, seed, arch)
+    tr = traffic.make_traffic(mix, seconds, seed, eng.model.cfg.vocab_size)
     adapter.warm_up(eng)
     active = {}
 
@@ -66,6 +67,7 @@ def window_at(cj: dict, mix: dict, rate: float, seed: int,
         "ttft_p95_ms": float(np.percentile(loop.ttft_s(), 95) * 1e3),
         "tpot_p95_ms": (float(np.percentile(tpot, 95) * 1e3)
                         if tpot.size else None),
+        "tpot_mean_ms": float(tpot.mean() * 1e3) if tpot.size else None,
         "output_tokens_per_s": loop.tokens_in_window() / seconds,
         "unanswered": loop.unanswered(),
         "follow_s": loop.t_stop - loop.t_end,
@@ -87,10 +89,10 @@ def main() -> int:
         print("sweep: JAX found no TPU; nothing was run", file=sys.stderr)
         return run.NO_DEVICE
     run.set_compile_cache(ROOT)
-    _, _, cj, mix = run.load_cell(ROOT, args.workload)
+    _, _, cj, mix, arch = run.load_cell(ROOT, args.workload)
     for rate in (float(r) for r in args.rates.split(",")):
         print(json.dumps({"workload": args.workload, **window_at(
-            cj, mix, rate, args.seed, args.seconds)}), flush=True)
+            cj, arch, mix, rate, args.seed, args.seconds)}), flush=True)
     return 0
 
 

@@ -6,6 +6,7 @@ from __future__ import annotations
 import json
 import os
 import shutil
+import subprocess
 import sys
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -61,6 +62,16 @@ def read_bench(root: str) -> dict:
 def write_bench(root: str, bench: dict) -> None:
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(bench, f, indent=1)
+
+
+def run_bench(root: str, *args, env_extra: dict | None = None):
+    """``bench/run.py`` of ``root`` in a process of its own, on the CPU."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(REPO, "src"))
+    env.update(env_extra or {})
+    return subprocess.run(
+        [sys.executable, os.path.join(root, "bench", "run.py"), *args],
+        capture_output=True, text=True, env=env, timeout=600)
 
 
 class SimClock:

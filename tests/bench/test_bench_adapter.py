@@ -10,8 +10,9 @@ import sys
 
 import pytest
 
-from benchroot import REPO, SimClock, fixture, make_root, read_bench, \
+from benchroot import SimClock, fixture, make_root, read_bench, \
     write_bench
+from benchroot import run_bench as _bench
 
 from bench import adapter, run, traffic  # noqa: E402
 
@@ -123,15 +124,6 @@ def test_traffic_is_the_same_work_in_another_order():
     assert [p.prompt for p in a.requests] != [p.prompt for p in b.requests]
     again = traffic.make_traffic(mix, 10, 1, 256)
     assert [p.prompt for p in a.requests] == [p.prompt for p in again.requests]
-
-
-def _bench(root, *args, env_extra=None):
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               PYTHONPATH=os.path.join(REPO, "src"))
-    env.update(env_extra or {})
-    return subprocess.run(
-        [sys.executable, os.path.join(root, "bench", "run.py"), *args],
-        capture_output=True, text=True, env=env, timeout=600)
 
 
 def test_result_line_holds_the_contract_keys(root):

@@ -16,7 +16,7 @@ import pytest
 
 from benchroot import FIXTURES, REPO
 
-from bench import run, trace
+from bench import arch, run, trace
 
 
 @pytest.fixture(scope="module")
@@ -59,7 +59,7 @@ def test_readers_on_the_trace(reduced):
     peaks = types.SimpleNamespace(bf16_flops_per_s=1e12,
                                   hbm_bytes_per_s=1e9)
     ctx = run.Ctx(loop=loop, m=m, peaks=peaks, reduced=reduced, setup_s=1.0,
-                  seconds=1e-4, drain_every=8)
+                  seconds=1e-4, drain_every=8, arch=arch.load("llama"))
 
     def read(name):
         return run.read_metric(REPO, name, ctx)
