@@ -43,6 +43,17 @@ class ModelConfig:
     rope_theta: float = 10000.0
     rope_fraction: float = 1.0  # fraction of head_dim that is rotated
     mrope_sections: tuple[int, ...] = ()  # in freq pairs; sums to rotated/2
+    # YaRN (DeepSeek-V2's rope_scaling), on where yarn_factor > 1: the
+    # frequencies blend theta^(-2i/d) / factor and theta^(-2i/d) over the
+    # correction range of beta_fast..beta_slow rotations in the original
+    # context; cos/sin are scaled by mscale(mscale) / mscale(mscale_all_dim)
+    # and MLA's softmax by mscale(mscale_all_dim)^2
+    yarn_factor: float = 1.0
+    yarn_original_max_pos: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # MLA (DeepSeek-V2 / MiniCPM3) -----------------------------------------
     use_mla: bool = False
@@ -58,6 +69,17 @@ class ModelConfig:
     n_shared_experts: int = 0
     d_ff_expert: int = 0
     first_dense_layers: int = 0  # leading dense layers before MoE layers
+    # routing: softmax over all n_experts; with n_group > 1 only the experts
+    # of the topk_group groups with the highest best score compete for top_k
+    # (DeepSeek-V2's group_limited_greedy); gates renormalised over the
+    # top_k, or else multiplied by routed_scaling_factor
+    n_group: int = 1
+    topk_group: int = 1
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    # the experts this device holds: (first, count), a contiguous range of
+    # the router's n_experts; count 0 means all of them
+    held_experts: tuple[int, int] = (0, 0)
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.001
 
@@ -102,6 +124,17 @@ class ModelConfig:
             return self.n_heads * (self.qk_nope_head_dim + self.qk_rope_head_dim)
         return self.n_heads * self.head_dim
 
+    @property
+    def held_range(self) -> tuple[int, int]:
+        """[first, end) of the experts held here."""
+        first, count = self.held_experts
+        return first, first + (count or self.n_experts)
+
+    @property
+    def n_held_experts(self) -> int:
+        lo, hi = self.held_range
+        return hi - lo
+
     def scaled(self, **overrides) -> "ModelConfig":
         return dataclasses.replace(self, **overrides)
 
@@ -133,7 +166,7 @@ class ModelConfig:
                 n += 2 * d * self.d_ff + d * d  # channel mix: k, v, r
             elif is_moe:
                 ff = self.d_ff_expert
-                n += self.n_experts * 3 * d * ff
+                n += self.n_held_experts * 3 * d * ff
                 n += self.n_shared_experts * 3 * d * ff
                 n += d * self.n_experts  # router
             else:

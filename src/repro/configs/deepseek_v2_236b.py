@@ -1,10 +1,12 @@
 """deepseek-v2-236b [arXiv:2405.04434; hf:deepseek-ai/DeepSeek-V2].
 
 60L, d_model=5120, 128 heads with **MLA** (q_lora=1536, kv_lora=512,
-qk_nope=128, qk_rope=64, v_head=128); MoE with 160 routed experts top-6 +
-2 shared experts, expert d_ff=1536, first layer dense (d_ff=12288);
-vocab=102400.  The MoE all-to-all makes this the paper-representative
-collective-bound hillclimb cell.
+qk_nope=128, qk_rope=64, v_head=128) under YaRN rotary scaling (factor 40
+over an original 4096 positions, beta 32/1, mscale = mscale_all_dim =
+0.707); MoE with 160 routed experts top-6 by group-limited greedy routing
+(8 groups, 3 chosen; softmax scores, gates not renormalised and scaled by
+16) + 2 shared experts, expert d_ff=1536, first layer dense (d_ff=12288);
+vocab=102400, untied head.
 """
 from repro.configs.base import ModelConfig, register
 
@@ -25,6 +27,13 @@ def config() -> ModelConfig:
         mlp_type="glu",
         act="silu",
         pos_type="rope",
+        rope_theta=10000.0,
+        yarn_factor=40.0,
+        yarn_original_max_pos=4096,
+        yarn_beta_fast=32.0,
+        yarn_beta_slow=1.0,
+        yarn_mscale=0.707,
+        yarn_mscale_all_dim=0.707,
         use_mla=True,
         q_lora_rank=1536,
         kv_lora_rank=512,
@@ -36,14 +45,21 @@ def config() -> ModelConfig:
         n_shared_experts=2,
         d_ff_expert=1536,
         first_dense_layers=1,
+        n_group=8,
+        topk_group=3,
+        norm_topk_prob=False,
+        routed_scaling_factor=16.0,
     )
 
 
 def smoke() -> ModelConfig:
+    # 8 experts in 4 groups, 2 chosen; an original context of 64 positions,
+    # so that YaRN's ramp falls inside the 4 rotary frequency pairs
     return config().scaled(
         n_layers=3, d_model=64, n_heads=4, n_kv_heads=4, head_dim=16,
         d_ff=192, vocab_size=256, q_lora_rank=32, kv_lora_rank=16,
         qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
         n_experts=8, top_k=2, n_shared_experts=1, d_ff_expert=48,
-        first_dense_layers=1, remat="none",
+        first_dense_layers=1, n_group=4, topk_group=2,
+        yarn_original_max_pos=64, remat="none",
     )

@@ -62,8 +62,10 @@ def _mlp_flops(cfg: ModelConfig, t: float) -> float:
 
 def _moe_flops(cfg: ModelConfig, t: float) -> float:
     d, ffe = cfg.d_model, cfg.d_ff_expert
+    # of a token's top-k, the share routed to the experts held here
+    held = cfg.n_held_experts / cfg.n_experts
     f = 2 * t * d * cfg.n_experts  # router
-    f += 2 * t * cfg.top_k * d * ffe * 3  # routed experts (glu)
+    f += 2 * t * cfg.top_k * held * d * ffe * 3  # routed experts (glu)
     f += 2 * t * d * (cfg.n_shared_experts * ffe) * 3  # shared experts
     return f
 

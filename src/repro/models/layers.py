@@ -9,6 +9,7 @@ launcher.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any
 
 import jax
@@ -90,10 +91,44 @@ def apply_norm(p: dict, cfg: ModelConfig, x: jax.Array) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's magnitude correction for a context stretched ``factor`` times."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(cfg: ModelConfig, rot_dim: int) -> tuple[int, int]:
+    """The frequency pairs [low, high] over which YaRN ramps from the plain
+    frequencies to the interpolated ones: those that turn beta_fast to
+    beta_slow times over the original context."""
+    def dim(rotations: float) -> float:
+        return (rot_dim * math.log(cfg.yarn_original_max_pos
+                                   / (rotations * 2 * math.pi))
+                / (2 * math.log(cfg.rope_theta)))
+
+    low = math.floor(dim(cfg.yarn_beta_fast))
+    high = math.ceil(dim(cfg.yarn_beta_slow))
+    return max(low, 0), min(high, rot_dim - 1)
+
+
 def rope_freqs(cfg: ModelConfig, rot_dim: int) -> jax.Array:
-    """Inverse frequencies for the rotated sub-dimension (pairs = rot_dim/2)."""
+    """Inverse frequencies for the rotated sub-dimension (pairs = rot_dim/2).
+    Under YaRN, pair i blends theta^(-2i/d) / factor (weight ramp_i) with
+    theta^(-2i/d) (weight 1 - ramp_i), ramp_i linear from 0 at ``low`` to 1
+    at ``high`` of the correction range."""
     exponent = jnp.arange(0, rot_dim, 2, dtype=jnp.float32) / rot_dim
-    return 1.0 / (cfg.rope_theta ** exponent)  # (rot_dim/2,)
+    plain = 1.0 / (cfg.rope_theta ** exponent)  # (rot_dim/2,)
+    if cfg.yarn_factor <= 1:
+        return plain
+    low, high = yarn_correction_range(cfg, rot_dim)
+    ramp = jnp.clip((jnp.arange(rot_dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return plain / cfg.yarn_factor * ramp + plain * (1.0 - ramp)
+
+
+def rope_magnitude(cfg: ModelConfig) -> float:
+    """The factor YaRN puts on cos and sin (1 without YaRN)."""
+    return (yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale)
+            / yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim))
 
 
 def apply_rope(x: jax.Array, positions: jax.Array, cfg: ModelConfig,
@@ -126,6 +161,9 @@ def apply_rope(x: jax.Array, positions: jax.Array, cfg: ModelConfig,
 
     sin = jnp.sin(angle)[..., None, :]  # (b, s, 1, rot/2)
     cos = jnp.cos(angle)[..., None, :]
+    mag = rope_magnitude(cfg)
+    if mag != 1.0:
+        sin, cos = sin * mag, cos * mag
     x_rot, x_pass = x[..., :rot], x[..., rot:]
     x1, x2 = x_rot[..., : rot // 2], x_rot[..., rot // 2 :]
     out1 = (x1.astype(jnp.float32) * cos - x2.astype(jnp.float32) * sin)

@@ -14,7 +14,8 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.distributed.sharding import constrain
 from repro.models.attention import ModelCtx, attention_core, kv_heads_shardable
-from repro.models.layers import Param, apply_norm, apply_rope, dense_init
+from repro.models.layers import (Param, apply_norm, apply_rope, dense_init,
+                                 yarn_mscale)
 
 
 def init_mla(key: jax.Array, cfg: ModelConfig) -> dict:
@@ -44,6 +45,13 @@ def init_mla(key: jax.Array, cfg: ModelConfig) -> dict:
     p["w_o"] = Param(dense_init(ks[6], (h, vdim, d), 2, dt),
                      ("heads", None, "embed_fsdp"))
     return p
+
+
+def softmax_scale(cfg: ModelConfig) -> float:
+    """(nope + rope)^-0.5, times mscale(factor, mscale_all_dim)^2 under
+    YaRN (DeepSeek-V2: 1.58963 x the plain scale)."""
+    m = yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim)
+    return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5 * m * m
 
 
 def _rms(p_scale: jax.Array, cfg: ModelConfig, x: jax.Array) -> jax.Array:
@@ -161,7 +169,7 @@ def apply_mla(
                        preferred_element_type=jnp.float32)
         s += jnp.einsum("bqhr,bsr->bhqs", q_rope, kr,
                         preferred_element_type=jnp.float32)
-        s *= (nope + rope) ** -0.5
+        s *= softmax_scale(cfg)
         mask = (pos_k[:, None, :] >= 0) & (pos_k[:, None, :] <= pos_q[:, :, None])
         s = jnp.where(mask[:, None], s, -0.7 * jnp.finfo(jnp.float32).max)
         w = jax.nn.softmax(s, axis=-1)
@@ -191,7 +199,8 @@ def apply_mla(
         q = jnp.concatenate([q_nope, q_rope], axis=-1)
         q = constrain(q, "batch", seq_ax, head_ax, None)
         pos = ctx.pos2d
-        o = attention_core(q, k, v, pos, pos, causal=ctx.causal)
+        o = attention_core(q, k, v, pos, pos, causal=ctx.causal,
+                           scale=softmax_scale(cfg))
         new_cache = None
         if cache is not None:  # prefill: persist compressed latents
             size = cache["ckv"].shape[1]

@@ -2,19 +2,27 @@
 
 Three execution paths with identical math (parity-tested):
 
-* ``_moe_dense``  — per-expert einsum over *all* tokens; used on a single
-  device (unit tests) and as the small-T GSPMD path for decode shapes, where
-  tokens are few (<= _SMALL_T) and a capacity all-to-all would be all overhead.
-  With a mesh active, experts stay sharded over the model axis and XLA inserts
-  one psum for the combine.
+* ``_moe_dense``  — per-expert einsum over *all* tokens for the experts this
+  device holds; used on a single device (unit tests, serving) and as the
+  small-T GSPMD path for decode shapes, where tokens are few (<= _SMALL_T)
+  and a capacity all-to-all would be all overhead.  With a mesh active,
+  experts stay sharded over the model axis and XLA inserts one psum for the
+  combine.
 * ``_moe_shard_map`` — the production train/prefill path: GShard-style
   capacity buffers, explicit ``all_to_all`` over the model ("expert") axis,
   FSDP all-gather of expert weights over the data axis, scatter-dispatch /
   gather-combine.  Tokens over (pod, data) x seq over model.
 
-Router: softmax -> top-k -> renormalised gates; standard load-balancing aux
-loss (Switch/GShard).  Over-capacity tokens are dropped (residual passes
-through), matching GShard semantics.
+Router: softmax over all ``n_experts`` -> (group-limited) top-k -> gates
+renormalised, or scaled by ``routed_scaling_factor``; standard
+load-balancing aux loss (Switch/GShard).  Over-capacity tokens are dropped
+(residual passes through), matching GShard semantics.
+
+Held experts: a layer may hold only a contiguous range of the router's
+experts (``cfg.held_experts``), as one device of an expert-parallel
+deployment does.  The router keeps its width over all of them; the layer
+computes, for each token, the part of the routed sum that its own experts
+give, and nothing for the rest.
 """
 from __future__ import annotations
 
@@ -40,16 +48,18 @@ def zero_stats() -> dict:
 
 
 def init_moe(key: jax.Array, cfg: ModelConfig) -> dict:
+    """The router over all ``n_experts``; the weights of the held ones."""
     d, e, ff = cfg.d_model, cfg.n_experts, cfg.d_ff_expert
+    held = cfg.n_held_experts
     dt = cfg.param_dtype
     ks = jax.random.split(key, 5)
     p = {
         "router": Param(dense_init(ks[0], (d, e), 1, dt), ("embed_fsdp", None)),
-        "w_gate": Param(dense_init(ks[1], (e, d, ff), 2, dt),
+        "w_gate": Param(dense_init(ks[1], (held, d, ff), 2, dt),
                         ("experts", "embed_fsdp", None)),
-        "w_up": Param(dense_init(ks[2], (e, d, ff), 2, dt),
+        "w_up": Param(dense_init(ks[2], (held, d, ff), 2, dt),
                       ("experts", "embed_fsdp", None)),
-        "w_down": Param(dense_init(ks[3], (e, ff, d), 2, dt),
+        "w_down": Param(dense_init(ks[3], (held, ff, d), 2, dt),
                         ("experts", "expert_ff_fsdp", None)),
     }
     return p
@@ -60,11 +70,25 @@ def _act(cfg: ModelConfig, x: jax.Array) -> jax.Array:
 
 
 def _route(router_w: jax.Array, x2d: jax.Array, cfg: ModelConfig):
-    """probs/top-k/aux from router logits.  x2d: (T, d)."""
+    """gates/top-k/aux from router logits over all ``n_experts``.
+    x2d: (T, d)."""
     logits = (x2d @ router_w.astype(jnp.float32)).astype(jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)  # (T, E)
-    gates, idx = jax.lax.top_k(probs, cfg.top_k)  # (T, K)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    scores = probs
+    if cfg.n_group > 1:
+        # group-limited greedy: keep the topk_group groups whose best expert
+        # scores highest, zero the others' scores, then take the top-k
+        t = probs.shape[0]
+        grouped = probs.reshape(t, cfg.n_group, -1)
+        _, keep = jax.lax.top_k(grouped.max(-1), cfg.topk_group)
+        mask = jnp.zeros((t, cfg.n_group), bool).at[
+            jnp.arange(t)[:, None], keep].set(True)
+        scores = jnp.where(mask[:, :, None], grouped, 0.0).reshape(t, -1)
+    gates, idx = jax.lax.top_k(scores, cfg.top_k)  # (T, K)
+    if cfg.norm_topk_prob:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    else:
+        gates = gates * cfg.routed_scaling_factor
     # Switch-style load balance loss: E * sum_e f_e * p_e
     e = cfg.n_experts
     me = jnp.mean(probs, axis=0)  # (E,) mean router prob
@@ -84,16 +108,20 @@ def _moe_dense(p: dict, cfg: ModelConfig, x: jax.Array) -> tuple[jax.Array, dict
     B, S, d = x.shape
     x2d = x.reshape(-1, d)
     gates, idx, aux = _route(p["router"], x2d, cfg)
-    # all-experts compute (T small): h (T, E, ff) with E sharded over model
+    # held-experts compute (T small): h (T, E_held, ff), E sharded over model
     h = jnp.einsum("td,edf->tef", x2d, p["w_gate"].astype(cdt))
     u = jnp.einsum("td,edf->tef", x2d, p["w_up"].astype(cdt))
     h = _act(cfg, h) * u
     h = constrain(h, None, "experts", None)
     y_e = jnp.einsum("tef,efd->ted", h, p["w_down"].astype(cdt))
     y_e = constrain(y_e, None, "experts", None)
-    # combine: sum_k gate_k * y_e[t, idx_k]
-    sel = jax.nn.one_hot(idx, cfg.n_experts, dtype=cdt)  # (T, K, E)
-    w_comb = jnp.einsum("tk,tke->te", gates.astype(cdt), sel)  # (T, E)
+    # combine: sum_k gate_k * y_e[t, idx_k] over the k whose expert is held
+    # (one_hot of an index outside [0, E_held) is all zeros)
+    lo, _ = cfg.held_range
+    if lo:
+        idx = idx - lo
+    sel = jax.nn.one_hot(idx, cfg.n_held_experts, dtype=cdt)  # (T, K, E_held)
+    w_comb = jnp.einsum("tk,tke->te", gates.astype(cdt), sel)  # (T, E_held)
     y = jnp.einsum("te,ted->td", w_comb, y_e)
     return y.reshape(B, S, d), {**zero_stats(), "load_balance": aux}
 
@@ -171,6 +199,10 @@ def _dispatch_compute_combine(
 
 
 def _moe_shard_map(p: dict, cfg: ModelConfig, x: jax.Array) -> tuple[jax.Array, dict]:
+    if cfg.n_held_experts != cfg.n_experts:
+        raise ValueError(
+            f"{cfg.name}: the capacity dispatch shards all {cfg.n_experts} "
+            f"experts over the mesh; this layer holds {cfg.n_held_experts}")
     info = current_mesh_info()
     data_axes, model_axis = shard_map_specs(info)
     mesh = info.mesh
